@@ -4,13 +4,18 @@ All pipelines are constructed through the unified facade —
 ``GLISPSystem.build(g, GLISPConfig(...))`` — never by hand-wiring servers
 and routers.  ``glisp_client`` / ``edgecut_client`` return the underlying
 ``SamplingService`` (the legacy client role) for benchmarks that poke
-workload counters directly.
+workload counters directly.  Importing it places JAX's persistent
+compilation cache for the benchmark scripts
+(``repro.launch.compile_cache``).
 """
 from __future__ import annotations
 
 import numpy as np
 
 from repro.api import GLISPConfig, GLISPSystem
+from repro.launch.compile_cache import setup_compile_cache
+
+setup_compile_cache()
 
 _CACHE: dict = {}
 

@@ -19,27 +19,23 @@ End-of-run asserts, per ISSUE 9:
 - the dp train-step losses match the single-device reference.
 
 Results land in ``BENCH_distributed.json`` (``--out``); ``--smoke``
-shrinks the workload for CI but keeps the full 1/2/4 sweeps.
+shrinks the workload for CI but keeps the full 1/2/4 sweeps.  Phase 2
+sweeps the shard counts that fit the devices JAX sees: on a CPU host, set
+``XLA_FLAGS=--xla_force_host_platform_device_count=4`` outside the script
+to get four.
 """
 from __future__ import annotations
 
-import os
+import argparse
+import json
+import time
 
-# the dp phase wants several host devices; XLA reads this before the
-# first jax import, so it must be set at module load
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=4"
-).strip()
+import jax
+import numpy as np
 
-import argparse  # noqa: E402
-import json  # noqa: E402
-import time  # noqa: E402
-
-import numpy as np  # noqa: E402
-
-from benchmarks.common import dataset, emit, glisp_system  # noqa: E402
-from repro.api import GLISPConfig, GLISPSystem  # noqa: E402
-from repro.core.sampling.service import SampleRequest, SamplingSpec  # noqa: E402
+from benchmarks.common import dataset, emit, glisp_system
+from repro.api import GLISPConfig, GLISPSystem
+from repro.core.sampling.service import SampleRequest, SamplingSpec
 
 RESULTS: dict = {}
 
@@ -152,6 +148,10 @@ def bench_workers(g, requests) -> None:
         )
 
 
+def _shard_sweep() -> tuple:
+    return tuple(s for s in SHARD_SWEEP if s <= len(jax.devices()))
+
+
 def bench_data_parallel(g, steps: int) -> None:
     from repro.launch.mesh import make_local_mesh
     from repro.models.gnn.models import GNNModel
@@ -159,7 +159,7 @@ def bench_data_parallel(g, steps: int) -> None:
     system = _remote_system(g, 2)
     try:
         ids = np.arange(min(4096, g.num_vertices), dtype=np.int64)
-        for shards in SHARD_SWEEP:
+        for shards in _shard_sweep():
             model = GNNModel(
                 "sage", g.vertex_feats.shape[1], hidden=32, num_layers=2,
                 num_classes=int(g.labels.max()) + 1,
@@ -216,7 +216,7 @@ def run(smoke: bool = False, out_json: str | None = "BENCH_distributed.json"):
             f"{top} workers did not reduce dispatch latency "
             f"(speedup {speedup:.2f}); smoke-sized workloads are exempt"
         )
-    for shards in SHARD_SWEEP:
+    for shards in _shard_sweep():
         assert RESULTS[f"shards{shards}/loss_matches_reference"], (
             f"{shards}-shard dp losses diverged from the single-device "
             "reference step"

@@ -1,25 +1,22 @@
-"""Fused GNN kernel suite benchmark: wall-clock + achieved-vs-peak roofline.
+"""GNN kernel suite benchmark: wall-clock + achieved-vs-peak roofline.
 
 Measures, at a padded bucket shape like the inference engine dispatches:
 
-* fused ``gather_spmm_pallas`` vs the unfused gather → ``segment_spmm_pallas``
-  sequence (the fusion win: no materialized [E, D] message array, and a 1-D
-  edge grid instead of re-reading every edge tile once per row block);
-* the ragged variant on a padding-heavy batch (3/4 padding), where all-pad
-  tiles cost one predicate instead of a matmul;
+* the ragged ``segment_spmm_ragged_pallas`` vs the row-tiled 2-D
+  ``segment_spmm_pallas`` on a padding-heavy batch (3/4 padding), where
+  all-pad tiles cost one predicate instead of a matmul;
 * the one-pass ``gat_softmax_aggregate_pallas`` vs the 3-pass
   segment-max → normalize → weighted-sum kernel sequence it replaces;
 * the deterministic autotuner (measured sweep, then in-memory and artifact
   cache hits);
-* per-kernel analytic FLOPs/bytes from ``launch.roofline.kernel_roofline``
-  so every wall-clock is stated against the hardware bound.
+* on a chip whose peaks ``launch.mesh.PEAKS`` knows, each kernel's
+  roofline from ``launch.roofline.kernel_roofline``; elsewhere only the
+  analytic FLOPs and HBM bytes.
 
 Everything asserts allclose against the jnp oracles in ``kernels/ref.py``.
-Wall-clocks here are Pallas **interpret mode** on CPU (this box), so
-absolute ``frac_of_peak`` numbers are tiny; the *relative* wins (fused vs
-unfused, ragged vs dense, one-pass vs 3-pass) are the grid-step and
-traffic savings that carry to hardware, and the analytic bounds in the
-report are hardware truths.  Results land in ``BENCH_kernels.json``.
+Kernels run in the platform's mode: compiled on a TPU, interpreted on the
+CPU, where the wall-clocks say nothing about the chip.  Results land in
+``BENCH_kernels.json``.
 """
 from __future__ import annotations
 
@@ -53,13 +50,11 @@ def _bench(fn, *args, reps: int = 3) -> float:
 def _inputs(E: int, N: int, D: int, valid: int, rng):
     import jax.numpy as jnp
 
-    feats = jnp.asarray(rng.standard_normal((N, D)).astype(np.float32))
-    idx = rng.integers(0, N, E).astype(np.int32)
+    msg = jnp.asarray(rng.standard_normal((E, D)).astype(np.float32))
     seg = np.sort(rng.integers(0, N, E)).astype(np.int32)
-    idx[valid:] = -1
     seg[valid:] = -1
     logits = jnp.asarray(rng.standard_normal(E).astype(np.float32))
-    return feats, jnp.asarray(idx), jnp.asarray(seg), logits
+    return msg, jnp.asarray(seg), logits
 
 
 def run(smoke: bool = False, out_json: str | None = "BENCH_kernels.json"):
@@ -69,77 +64,52 @@ def run(smoke: bool = False, out_json: str | None = "BENCH_kernels.json"):
     from repro.kernels import autotune as at
     from repro.kernels.fused_gnn import (
         gat_softmax_aggregate_pallas,
-        gather_spmm_pallas,
-        gather_spmm_ragged_pallas,
+        segment_spmm_ragged_pallas,
     )
-    from repro.kernels.ops import INTERPRET
-    from repro.kernels.ref import gat_softmax_aggregate_ref, gather_spmm_ref
+    from repro.kernels.ref import gat_softmax_aggregate_ref, segment_spmm_ref
     from repro.kernels.segment_spmm import segment_spmm_pallas
-    from repro.launch.roofline import kernel_roofline
+    from repro.launch.roofline import kernel_flops, kernel_hbm_bytes, kernel_roofline
+
+    dev = jax.devices()[0]
+    RESULTS["device"] = {"platform": dev.platform, "kind": dev.device_kind}
+
+    def _roofline(op, shape, wall):
+        if dev.platform == "tpu":
+            _emit(f"roofline/{op}", kernel_roofline(op, shape, wall, dev.device_kind))
+        else:
+            _emit(f"analytic/{op}/flops", kernel_flops(op, shape))
+            _emit(f"analytic/{op}/hbm_bytes", kernel_hbm_bytes(op, shape))
 
     E, N, D = (1024, 128, 16) if smoke else (8192, 1024, 64)
     rng = np.random.default_rng(0)
-    feats, idx, seg, logits = _inputs(E, N, D, valid=E, rng=rng)
-    shape = {"edges": E, "segments": N, "dim": D, "feat_rows": N}
+    shape = {"edges": E, "segments": N, "dim": D}
 
-    # --- fused gather+aggregate vs the unfused sequence -------------------
-    @jax.jit
-    def unfused(feats, idx, seg):
-        ok = (idx >= 0) & (seg >= 0)
-        msg = jnp.where(ok[:, None], feats[jnp.maximum(idx, 0)], 0.0)
-        return segment_spmm_pallas(msg, seg, N, interpret=INTERPRET)
+    # --- ragged vs row-tiled segment-SpMM on a 3/4-padding bucket ---------
+    msg_q, seg_q, _ = _inputs(E, N, D, valid=E // 4, rng=rng)
+    ref_q = np.asarray(segment_spmm_ref(msg_q, seg_q, N))
 
-    @jax.jit
-    def oracle(feats, idx, seg):
-        return gather_spmm_ref(feats, idx, seg, N)
+    def dense_q(msg, seg):
+        return segment_spmm_pallas(msg, seg, N)
 
-    def fused(feats, idx, seg):
-        return gather_spmm_pallas(feats, idx, seg, N, interpret=INTERPRET)
+    def ragged_q(msg, seg):
+        return segment_spmm_ragged_pallas(msg, seg, N)
 
-    ref = oracle(feats, idx, seg)
-    assert np.allclose(np.asarray(fused(feats, idx, seg)), ref, rtol=1e-4, atol=1e-5)
-    t_unfused = _bench(unfused, feats, idx, seg)
-    t_fused = _bench(fused, feats, idx, seg)
-    t_oracle = _bench(oracle, feats, idx, seg)
-    _emit("gather_spmm/unfused_s", t_unfused)
-    _emit("gather_spmm/fused_s", t_fused)
-    _emit("gather_spmm/jnp_oracle_s", t_oracle)
-    _emit("gather_spmm/fused_speedup_vs_unfused", t_unfused / t_fused)
-    for op, wall in (
-        ("unfused_gather_spmm", t_unfused),
-        ("gather_spmm", t_fused),
-    ):
-        _emit(f"roofline/{op}", kernel_roofline(op, shape, wall))
-
-    # --- ragged variant on a padding-heavy bucket (3/4 padding) -----------
-    _, idx_q, seg_q, _ = _inputs(E, N, D, valid=E // 4, rng=rng)
-
-    def fused_dense_q(feats, idx, seg):
-        return gather_spmm_pallas(feats, idx, seg, N, interpret=INTERPRET)
-
-    def fused_ragged_q(feats, idx, seg):
-        return gather_spmm_ragged_pallas(feats, idx, seg, N, interpret=INTERPRET)
-
-    ref_q = np.asarray(oracle(feats, idx_q, seg_q))
-    assert np.allclose(
-        np.asarray(fused_ragged_q(feats, idx_q, seg_q)), ref_q, rtol=1e-4, atol=1e-5
-    )
-    t_dense_q = _bench(fused_dense_q, feats, idx_q, seg_q)
-    t_ragged_q = _bench(fused_ragged_q, feats, idx_q, seg_q)
+    for fn in (dense_q, ragged_q):
+        assert np.allclose(np.asarray(fn(msg_q, seg_q)), ref_q, rtol=1e-4, atol=1e-4)
+    t_dense_q = _bench(dense_q, msg_q, seg_q)
+    t_ragged_q = _bench(ragged_q, msg_q, seg_q)
     _emit("ragged/dense_s", t_dense_q)
     _emit("ragged/ragged_s", t_ragged_q)
     _emit("ragged/speedup_on_3quarters_padding", t_dense_q / t_ragged_q)
-    _emit(
-        "roofline/gather_spmm_ragged",
-        kernel_roofline("gather_spmm_ragged", {**shape, "valid_edges": E // 4}, t_ragged_q),
-    )
+    _roofline("segment_spmm", shape, t_dense_q)
+    _roofline("segment_spmm_ragged", {**shape, "valid_edges": E // 4}, t_ragged_q)
 
     # --- one-pass GAT softmax+aggregate vs the 3-pass it replaces ---------
-    msg = jnp.take(feats, jnp.maximum(idx, 0), axis=0)
+    msg, seg, logits = _inputs(E, N, D, valid=E, rng=rng)
 
-    # The exact pre-fusion kernel path from models.py: jnp segment-max, then
-    # two 2-D-grid segment_spmm calls (one for the softmax denominator, one
-    # for the weighted sum) — 3 passes over the edge array.
+    # The pre-fusion kernel path from models.py: jnp segment-max, then two
+    # 2-D-grid segment_spmm calls (one for the softmax denominator, one for
+    # the weighted sum) — 3 passes over the edge array.
     @jax.jit
     def three_pass(logits, msg, seg):
         ok = seg >= 0
@@ -149,38 +119,37 @@ def run(smoke: bool = False, out_json: str | None = "BENCH_kernels.json"):
         )
         mx = jnp.where(jnp.isfinite(mx), mx, 0.0)
         e = jnp.where(ok, jnp.exp(logits - mx[seg0]), 0.0)
-        z = segment_spmm_pallas(e[:, None], seg, N, interpret=INTERPRET)[:, 0]
+        z = segment_spmm_pallas(e[:, None], seg, N)[:, 0]
         alpha = e / jnp.maximum(z[seg0], 1e-9)
-        return segment_spmm_pallas(msg * alpha[:, None], seg, N, interpret=INTERPRET)
+        return segment_spmm_pallas(msg * alpha[:, None], seg, N)
 
     def one_pass(logits, msg, seg):
-        return gat_softmax_aggregate_pallas(logits, msg, seg, N, interpret=INTERPRET)
+        return gat_softmax_aggregate_pallas(logits, msg, seg, N)
 
     ref_gat = np.asarray(gat_softmax_aggregate_ref(logits, msg, seg, N))
-    assert np.allclose(
-        np.asarray(one_pass(logits, msg, seg)), ref_gat, rtol=1e-4, atol=1e-5
-    )
-    assert np.allclose(
-        np.asarray(three_pass(logits, msg, seg)), ref_gat, rtol=1e-4, atol=1e-5
-    )
+    for fn in (one_pass, three_pass):
+        assert np.allclose(
+            np.asarray(fn(logits, msg, seg)), ref_gat, rtol=1e-4, atol=1e-4
+        )
     t3 = _bench(three_pass, logits, msg, seg)
     t1 = _bench(one_pass, logits, msg, seg)
     _emit("gat/three_pass_s", t3)
     _emit("gat/one_pass_s", t1)
     _emit("gat/one_pass_speedup", t3 / t1)
-    _emit("roofline/gat_softmax_aggregate", kernel_roofline("gat_softmax_aggregate", shape, t1))
+    _roofline("gat_softmax_aggregate", shape, t1)
 
     # --- deterministic autotuner ------------------------------------------
     at.reset()
     tune_shape = (E, N, D)
+    op = "segment_spmm_ragged"
     with tempfile.TemporaryDirectory() as td:
         t0 = time.perf_counter()
-        cfg1 = at.autotune("gather_spmm_ragged", tune_shape, np.float32, cache_dir=td)
+        cfg1 = at.autotune(op, tune_shape, np.float32, cache_dir=td)
         t_sweep = time.perf_counter() - t0
-        cfg2 = at.autotune("gather_spmm_ragged", tune_shape, np.float32, cache_dir=td)
+        cfg2 = at.autotune(op, tune_shape, np.float32, cache_dir=td)
         assert cfg1 == cfg2 and at.stats()["memory_hits"] == 1
         at.reset(clear_stats=False)  # fresh process simulation
-        cfg3 = at.autotune("gather_spmm_ragged", tune_shape, np.float32, cache_dir=td)
+        cfg3 = at.autotune(op, tune_shape, np.float32, cache_dir=td)
         assert cfg3 == cfg1 and at.stats()["artifact_hits"] == 1
     _emit("autotune/sweep_s", t_sweep)
     _emit("autotune/chosen_block_edges", cfg1.block_edges)
@@ -188,22 +157,6 @@ def run(smoke: bool = False, out_json: str | None = "BENCH_kernels.json"):
     _emit("autotune/memory_hits", at.stats()["memory_hits"])
     _emit("autotune/artifact_hits", at.stats()["artifact_hits"])
     at.reset()
-
-    # --- acceptance: fused beats unfused; ragged beats dense on padding ---
-    # Perf gates hold at benchmark scale; smoke runs are too small for the
-    # wall-clock deltas to clear timer noise, so smoke only checks numerics.
-    if not smoke:
-        assert t_fused < t_unfused, (
-            f"fused gather+aggregate ({t_fused:.4f}s) must beat the unfused "
-            f"gather->segment_spmm sequence ({t_unfused:.4f}s)"
-        )
-        assert t_ragged_q < t_dense_q, (
-            f"ragged kernel ({t_ragged_q:.4f}s) must beat dense ({t_dense_q:.4f}s) "
-            "on a 3/4-padding bucket"
-        )
-        assert t1 < t3, (
-            f"one-pass GAT kernel ({t1:.4f}s) must beat the 3-pass sequence ({t3:.4f}s)"
-        )
 
     if out_json:
         with open(out_json, "w") as f:

@@ -3,19 +3,11 @@ GCN/GraphSAGE/GAT on the GLISP pipeline vs the edge-cut pipeline, plus the
 prefetching batch pipeline vs the serial sample-then-step path.
 
 All systems are assembled via ``GLISPSystem.build`` (benchmarks/common.py).
-
-The prefetch comparison emulates the accelerator deployment on a CPU-only
-box with an explicit host/device core split: the training process (XLA) is
-pinned to core 0 in BOTH modes, and the prefetch sampling worker gets core 1
-— on real hardware the device computes off-CPU so this split is free, while
-here XLA would otherwise saturate every core and leave the sampler nothing
-to overlap into.  The split must be installed before XLA spins up its
-thread pool, hence a fresh subprocess.
+Both sections run in this one process: on a TPU the process that first
+touches JAX holds the chip, so a child process could not reach it.
 """
 from __future__ import annotations
 
-import os
-import subprocess
 import sys
 import time
 
@@ -73,19 +65,6 @@ def run_system_comparison(g, ids, n_train):
         emit(f"fig11/{model_kind}/sampling_speedup", pe / max(pg, 1e-9))
 
 
-def _pin_host_device_split():
-    """Pin this (XLA) process to core 0, reserving core 1 for the sampling
-    worker.  Returns the worker's core set, or None when the box has a
-    single core (no split possible — overlap then has nothing to run on)."""
-    if not hasattr(os, "sched_setaffinity"):
-        return None
-    cores = sorted(os.sched_getaffinity(0))
-    if len(cores) < 2:
-        return None
-    os.sched_setaffinity(0, {cores[0]})
-    return (cores[1],)
-
-
 def run_prefetch_comparison(g, ids, n_train, reps=3):
     """Measured wall-clock of one epoch: serial sample-then-step vs the
     double-buffered prefetching pipeline.  Each mode gets a freshly built,
@@ -94,7 +73,6 @@ def run_prefetch_comparison(g, ids, n_train, reps=3):
     alternate serial/prefetch for ``reps`` rounds and the MIN wall per mode
     is compared — the container shares its host, so min-of-paired-runs
     filters neighbor noise out of both sides equally."""
-    worker_cores = _pin_host_device_split()
     trainers = {}
     for mode, depth in (("serial", 0), ("prefetch", 2)):
         system = GLISPSystem.build(g, GLISPConfig(
@@ -103,8 +81,7 @@ def run_prefetch_comparison(g, ids, n_train, reps=3):
         ))
         model = GNNModel("sage", g.vertex_feats.shape[1], hidden=64,
                          num_layers=3, num_classes=3)
-        tr = system.trainer(model, ids[:n_train], opt=_opt(400),
-                            worker_cores=worker_cores)
+        tr = system.trainer(model, ids[:n_train], opt=_opt(400))
         tr.train(epochs=1, log_every=10**9)  # warm-up: compile all buckets
         trainers[mode] = tr
     walls = {mode: [] for mode in trainers}
@@ -135,26 +112,10 @@ def _bench_data():
     return g, ids, int(0.7 * len(ids))
 
 
-def run_prefetch_comparison_subprocess():
-    """Re-exec the prefetch section in a fresh process: the host/device core
-    split must be installed before XLA creates its intra-op thread pool."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in [os.path.join(root, "src"), root, env.get("PYTHONPATH", "")] if p
-    )
-    subprocess.run(
-        [sys.executable, "-m", "benchmarks.train_e2e", "--prefetch-only"],
-        env=env,
-        cwd=root,
-        check=True,
-    )
-
-
 def run():
     g, ids, n_train = _bench_data()
     run_system_comparison(g, ids, n_train)
-    run_prefetch_comparison_subprocess()
+    run_prefetch_comparison(g, ids, n_train)
 
 
 if __name__ == "__main__":

@@ -17,6 +17,9 @@ from repro.core.sampling import GatherApplyClient, SamplingServer, VertexRouter
 from repro.graph import build_partitions, named_dataset
 from repro.models.gnn import GNNModel, subgraph_to_batch
 from repro.train.optim import AdamWConfig, adamw_init, adamw_update
+from repro.launch.compile_cache import setup_compile_cache
+
+setup_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=60)

@@ -17,6 +17,9 @@ from repro.api import GLISPConfig, GLISPSystem
 from repro.core.inference import samplewise_inference
 from repro.core.storage import IOCost
 from repro.graph import power_law_graph
+from repro.launch.compile_cache import setup_compile_cache
+
+setup_compile_cache()
 
 g = power_law_graph(12000, avg_degree=8, seed=1, feat_dim=32)
 system = GLISPSystem.build(g, GLISPConfig(

@@ -31,6 +31,9 @@ from repro.api import GLISPConfig, GLISPSystem, SamplingSpec
 from repro.graph import power_law_graph
 from repro.models.gnn import GNNModel
 from repro.train.optim import AdamWConfig
+from repro.launch.compile_cache import setup_compile_cache
+
+setup_compile_cache()
 
 print("== 1. generate graph ==")
 g = power_law_graph(8000, avg_degree=10, seed=0, feat_dim=32, num_classes=0)

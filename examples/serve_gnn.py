@@ -18,6 +18,9 @@ import numpy as np
 from repro.api import FaultPlan, FaultSpec, GLISPConfig, GLISPSystem, RetryPolicy
 from repro.graph import power_law_graph
 from repro.models.gnn import GNNModel
+from repro.launch.compile_cache import setup_compile_cache
+
+setup_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--vertices", type=int, default=3000)

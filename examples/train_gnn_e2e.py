@@ -16,6 +16,9 @@ from repro.graph import named_dataset
 from repro.models.gnn import GNNModel
 from repro.train import save_checkpoint
 from repro.train.optim import AdamWConfig
+from repro.launch.compile_cache import setup_compile_cache
+
+setup_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--dataset", default="ogbn-paper")

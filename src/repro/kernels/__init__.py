@@ -1,10 +1,10 @@
-# Pallas TPU kernels for the compute hot-spots (validated interpret=True on
-# CPU): segment_spmm (GNN aggregation) plus its fused/ragged variants in
-# fused_gnn.py, flash_attention, ssd_scan (Mamba-2).  Block sizes resolve
+# Pallas TPU kernels for the compute hot-spots: segment_spmm (GNN
+# aggregation) plus its ragged/GAT/segment-max variants in fused_gnn.py,
+# flash_attention, ssd_scan (Mamba-2).  They compile with Mosaic on a TPU
+# and run in interpret mode elsewhere (mode.py).  Block sizes resolve
 # through the deterministic autotuner in autotune.py.
 from repro.kernels.autotune import DEFAULT_CONFIG, KernelConfig, get_tuned
 from repro.kernels.ops import (
-    INTERPRET,
     gnn_aggregate,
     gnn_gat_aggregate,
     gnn_gather_aggregate,
@@ -14,7 +14,6 @@ from repro.kernels.ops import (
 )
 
 __all__ = [
-    "INTERPRET",
     "gnn_aggregate",
     "gnn_gather_aggregate",
     "gnn_gat_aggregate",

@@ -49,15 +49,13 @@ __all__ = [
 TUNE_VERSION = 1
 
 # fixed candidate grids (order matters: ties resolve to the earlier entry).
-# Only segment_spmm tiles the row axis; the fused kernels run a 1-D edge
+# Only segment_spmm tiles the row axis; the other kernels run a 1-D edge
 # grid with the full output resident, so only block_edges is swept there.
 _EDGE_CANDIDATES = (64, 128, 256)
 _ROW_CANDIDATES = (128, 256)
 TUNED_OPS = (
     "segment_spmm",
     "segment_spmm_ragged",
-    "gather_spmm",
-    "gather_spmm_ragged",
     "gat_softmax_aggregate",
     "segment_max",
 )
@@ -169,23 +167,18 @@ def _inputs(op: str, shape, dtype):
     valid = (3 * edges) // 4
     seg = np.sort(rng.integers(0, segments, edges)).astype(np.int32)
     seg[valid:] = -1
-    idx = rng.integers(0, segments, edges).astype(np.int32)
-    idx[valid:] = -1
-    feats = rng.standard_normal((segments, dim)).astype(np.float32)
     msg = rng.standard_normal((edges, dim)).astype(np.float32)
     logits = rng.standard_normal(edges).astype(np.float32)
     cast = lambda a: jnp.asarray(a, dtype=dtype)  # noqa: E731
     return {
         "seg": jnp.asarray(seg),
-        "idx": jnp.asarray(idx),
-        "feats": cast(feats),
         "msg": cast(msg),
         "logits": cast(logits),
         "n": segments,
     }
 
 
-def _call(op: str, inp: dict, cfg: KernelConfig, interpret: bool):
+def _call(op: str, inp: dict, cfg: KernelConfig, interpret: bool | None):
     from repro.kernels import fused_gnn, segment_spmm
 
     n, be = inp["n"], cfg.block_edges
@@ -197,16 +190,6 @@ def _call(op: str, inp: dict, cfg: KernelConfig, interpret: bool):
     if op == "segment_spmm_ragged":
         return fused_gnn.segment_spmm_ragged_pallas(
             inp["msg"], inp["seg"], n, block_edges=be, interpret=interpret
-        )
-    if op == "gather_spmm":
-        return fused_gnn.gather_spmm_pallas(
-            inp["feats"], inp["idx"], inp["seg"], n,
-            block_edges=be, interpret=interpret,
-        )
-    if op == "gather_spmm_ragged":
-        return fused_gnn.gather_spmm_ragged_pallas(
-            inp["feats"], inp["idx"], inp["seg"], n,
-            block_edges=be, interpret=interpret,
         )
     if op == "gat_softmax_aggregate":
         return fused_gnn.gat_softmax_aggregate_pallas(
@@ -241,7 +224,9 @@ def autotune(
 ) -> KernelConfig:
     """Best block config for (op, shape-bucket, dtype): in-process table
     first, then the cache artifact, then a measured sweep (whose winner is
-    merged back into the artifact when ``cache_dir`` is given)."""
+    merged back into the artifact when ``cache_dir`` is given).  The sweep
+    runs the kernels in the platform's mode unless ``interpret`` says
+    otherwise."""
     if op not in CANDIDATES:
         raise ValueError(f"unknown tuned op {op!r} (have {sorted(CANDIDATES)})")
     key = tuned_key(op, shape, dtype)
@@ -254,10 +239,6 @@ def autotune(
             _STATS["artifact_hits"] += 1
             _TUNED[key] = cached[key]
             return cached[key]
-    if interpret is None:
-        from repro.kernels.ops import INTERPRET
-
-        interpret = INTERPRET
     inp = _inputs(op, shape, dtype)
     times = [_measure(op, inp, c, repeats, interpret) for c in CANDIDATES[op]]
     best = CANDIDATES[op][int(np.argmin(times))]  # ties -> earlier candidate

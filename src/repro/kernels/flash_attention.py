@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.mode import interpret_mode
+
 __all__ = ["flash_attention_pallas"]
 
 _NEG_INF = -1e30
@@ -98,7 +100,7 @@ def flash_attention_pallas(
     kv_offset: int = 0,
     block_q: int = 128,
     block_kv: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Single-head attention.  q: [Sq, D]; k, v: [Skv, D].
 
@@ -142,7 +144,7 @@ def flash_attention_pallas(
             jax.ShapeDtypeStruct((q_pad, 1), jnp.float32),
             jax.ShapeDtypeStruct((q_pad, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)
     out = out / jnp.maximum(l, 1e-30)
     return out[:sq].astype(q.dtype)

@@ -1,33 +1,37 @@
-"""Fused ragged Pallas kernels for the GNN hot path.
+"""Ragged Pallas kernels for the GNN hot path.
 
-The bucketed inference engine and the training apply() path both reduce to
-the same three dispatches per layer: gather neighbor rows, segment-sum them
-into destination rows, normalize/activate.  ``segment_spmm.py`` already
-turns the scatter into a block-tiled one-hot matmul; this module removes
-the remaining HBM round trips and padding waste (ROADMAP item 1):
+The bucketed inference engine and the training apply() path both reduce
+to the same dispatches per layer: gather neighbor rows, segment-sum them
+into destination rows, normalize/activate.  This module holds the
+aggregation kernels the dispatchers in ``ops.py`` reach:
 
-* :func:`gather_spmm_pallas` — fused gather+segment-SpMM.  Takes the
-  feature matrix and per-edge row indices and gathers *inside* the edge
-  tile, so the ``[E, D]`` message array is never materialized in HBM.
-* :func:`gather_spmm_ragged_pallas` / :func:`segment_spmm_ragged_pallas` —
-  masked/ragged variants driven by per-tile valid-edge counts.  Power-of-two
-  bucket padding then costs one ``pl.when`` predicate per tile instead of
-  MXU work (an all-padding tile is skipped entirely).
+* :func:`segment_spmm_ragged_pallas` — segment-sum of pre-gathered
+  messages on a 1-D grid over edge tiles.  Per-tile valid-edge counts are
+  scalar-prefetched into SMEM, so a tile of bucket padding costs one
+  predicate instead of a matmul.
 * :func:`gat_softmax_aggregate_pallas` — one-pass GAT edge-softmax +
   weighted aggregate (segment-max, exp, normalize, weighted segment-sum in
   a single kernel), replacing the 3-pass ``_seg_softmax`` + ``_seg_sum``
   sequence in ``models/gnn/models.py``.  Uses the flash-attention online
-  rescaling trick (running max / denominator / accumulator as revisited
-  output blocks) so segments can span edge tiles.
+  rescaling trick (running max / denominator in VMEM scratch, accumulator
+  as the revisited output block) so segments can span edge tiles.
 * :func:`segment_max_pallas` — standalone segment-max so ``_seg_softmax``'s
   max step can honor ``use_kernel`` too.
 
-All kernels run on a 1-D grid over edge tiles with the full output array as
-a revisited block: the gather happens once per edge tile (never once per
-(row-tile, edge-tile) pair), which is also what makes the fused path beat
-gather→``segment_spmm_pallas`` on wall-clock.  ``seg == -1`` / ``idx == -1``
-mark padding.  Every kernel has a same-named ``*_ref`` oracle in ``ref.py``
-(glint rule KRN001 enforces this) and plumbs ``interpret`` through.
+Layout, as the TPU's kernel compiler (Mosaic) needs it: per-edge vectors
+(segment ids, logits) are reshaped to ``(tiles, 1, block_edges)`` so each
+tile reads one lane-dense ``[1, block_edges]`` row, and membership of edge
+``e`` in segment ``r`` is the int32 comparison ``iota[r, e] == seg[0, e]``
+over an ``[n, block_edges]`` tile — never a reshaped bool vector.  The
+scatter is then ``onehot @ msg`` on the MXU, at HIGHEST precision for f32
+messages so the sum is as exact as the jnp oracle's.  The full ``[n, d]``
+output is a revisited block; its VMEM footprint bounds ``n`` (the engine's
+vertex bucket), and ``compiler_params`` raise the scoped limit to fit it.
+
+``seg == -1`` marks padding.  Every kernel has a same-named ``*_ref``
+oracle in ``ref.py`` (glint rule KRN001 enforces this) and takes an
+``interpret`` argument; ``None`` picks the mode from the platform
+(:func:`repro.kernels.mode.interpret_mode`).
 """
 from __future__ import annotations
 
@@ -36,10 +40,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.mode import interpret_mode
 
 __all__ = [
-    "gather_spmm_pallas",
-    "gather_spmm_ragged_pallas",
     "segment_spmm_ragged_pallas",
     "gat_softmax_aggregate_pallas",
     "segment_max_pallas",
@@ -63,155 +68,57 @@ def _pad_edges(arrs, m: int, block_edges: int, fills):
     return out, m_pad
 
 
-def _onehot(seg, valid, n):
-    """[BM, n] one-hot membership matrix (bool), padding rows all-zero."""
-    rows = jax.lax.iota(jnp.int32, n)
-    return (seg[:, None] == rows[None, :]) & valid[:, None]
+def edge_rows(x: jax.Array, block_edges: int) -> jax.Array:
+    """``[m_pad]`` per-edge vector -> ``(tiles, 1, block_edges)``."""
+    return x.reshape(-1, 1, block_edges)
 
 
-# -- fused gather + segment-SpMM --------------------------------------------
+def member(seg_row: jax.Array, n: int, base=0) -> jax.Array:
+    """``[n, BM]`` bool: segment ``base + r`` holds edge ``e`` iff
+    ``seg_row[0, e] == base + r``; padding (-1) matches no row."""
+    rows = base + jax.lax.broadcasted_iota(jnp.int32, (n, seg_row.shape[-1]), 0)
+    return rows == seg_row
 
 
-def _gather_accumulate(idx_ref, seg_ref, feats_ref, out_ref):
-    idx = idx_ref[...]  # [BM] int32 rows into feats (-1 = padding)
-    seg = seg_ref[...]  # [BM] int32 destination segments (-1 = padding)
-    feats = feats_ref[...]  # [F, D] resident feature block
-    msg = jnp.take(feats, jnp.maximum(idx, 0), axis=0)  # [BM, D] in VMEM only
-    ok = (idx >= 0) & (seg >= 0)
-    onehot = _onehot(seg, ok, out_ref.shape[0]).astype(msg.dtype)
-    out_ref[...] += jax.lax.dot_general(
-        onehot,
+def onehot(mask: jax.Array, dtype) -> jax.Array:
+    """A membership mask as exact 0/1 weights."""
+    return jnp.where(mask, 1.0, 0.0).astype(dtype)
+
+
+def scatter_sum(w: jax.Array, msg: jax.Array) -> jax.Array:
+    """``w [n, BM] @ msg [BM, d]`` with f32 accumulation: sums each tile's
+    (weighted) messages into their segments."""
+    return jax.lax.dot_general(
+        w,
         msg,
-        dimension_numbers=(((0,), (0,)), ((), ())),  # onehot^T @ msg
-        preferred_element_type=out_ref.dtype,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST if msg.dtype == jnp.float32 else None,
+        preferred_element_type=jnp.float32,
     )
 
 
-def _gather_kernel(idx_ref, seg_ref, feats_ref, out_ref):
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    _gather_accumulate(idx_ref, seg_ref, feats_ref, out_ref)
-
-
-def _gather_ragged_kernel(cnt_ref, idx_ref, seg_ref, feats_ref, out_ref):
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(cnt_ref[0] > 0)  # all-padding tiles cost one predicate
-    def _compute():
-        _gather_accumulate(idx_ref, seg_ref, feats_ref, out_ref)
-
-
-def _gather_call(kernel, inputs, specs, grid, n, d, dtype, interpret):
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=specs,
-        out_specs=pl.BlockSpec((n, d), lambda eb: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, d), dtype),
-        interpret=interpret,
-    )(*inputs)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("num_segments", "block_edges", "interpret")
-)
-def gather_spmm_pallas(
-    feats: jax.Array,
-    idx: jax.Array,
-    seg: jax.Array,
-    num_segments: int,
-    *,
-    block_edges: int = 128,
-    interpret: bool = True,
-) -> jax.Array:
-    """out[s] = sum over edges e with seg[e] == s of feats[idx[e]].
-
-    feats: [F, D]; idx, seg: [E] int32 with -1 padding.  The gather runs
-    inside the edge tile — no [E, D] message array is ever materialized."""
-    m = idx.shape[0]
-    f, d = feats.shape
-    (idx, seg), m_pad = _pad_edges(
-        [idx.astype(jnp.int32), seg.astype(jnp.int32)], m, block_edges, [-1, -1]
-    )
-    return _gather_call(
-        _gather_kernel,
-        (idx, seg, feats),
-        [
-            pl.BlockSpec((block_edges,), lambda eb: (eb,)),
-            pl.BlockSpec((block_edges,), lambda eb: (eb,)),
-            pl.BlockSpec((f, d), lambda eb: (0, 0)),
-        ],
-        (m_pad // block_edges,),
-        num_segments,
-        d,
-        feats.dtype,
-        interpret,
-    )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("num_segments", "block_edges", "interpret")
-)
-def gather_spmm_ragged_pallas(
-    feats: jax.Array,
-    idx: jax.Array,
-    seg: jax.Array,
-    num_segments: int,
-    *,
-    block_edges: int = 128,
-    interpret: bool = True,
-) -> jax.Array:
-    """Ragged :func:`gather_spmm_pallas`: per-tile valid-edge counts are
-    computed host-side-of-the-kernel and tiles with zero valid edges skip
-    the gather+matmul entirely (bucket padding costs mask work, not MXU
-    work).  Same semantics as the dense variant."""
-    m = idx.shape[0]
-    f, d = feats.shape
-    (idx, seg), m_pad = _pad_edges(
-        [idx.astype(jnp.int32), seg.astype(jnp.int32)], m, block_edges, [-1, -1]
-    )
-    valid = (idx >= 0) & (seg >= 0)
-    counts = jnp.sum(valid.reshape(-1, block_edges), axis=1).astype(jnp.int32)
-    return _gather_call(
-        _gather_ragged_kernel,
-        (counts, idx, seg, feats),
-        [
-            pl.BlockSpec((1,), lambda eb: (eb,)),
-            pl.BlockSpec((block_edges,), lambda eb: (eb,)),
-            pl.BlockSpec((block_edges,), lambda eb: (eb,)),
-            pl.BlockSpec((f, d), lambda eb: (0, 0)),
-        ],
-        (m_pad // block_edges,),
-        num_segments,
-        d,
-        feats.dtype,
-        interpret,
-    )
+# Scoped-VMEM budget of the resident-output kernels: three quarters of a
+# v5e core's 128 MiB.  The default 16 MiB is too small once the revisited
+# [n, d] output and the [n, block_edges] tile temporaries grow to the
+# engine's 4096-row vertex bucket at d = 256.
+VMEM_LIMIT = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
 
 
 # -- ragged segment-SpMM (pre-gathered messages) -----------------------------
 
 
 def _spmm_ragged_kernel(cnt_ref, seg_ref, msg_ref, out_ref):
-    @pl.when(pl.program_id(0) == 0)
+    eb = pl.program_id(0)
+
+    @pl.when(eb == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(cnt_ref[0] > 0)
+    @pl.when(cnt_ref[eb] > 0)  # all-padding tiles cost one predicate
     def _compute():
-        seg = seg_ref[...]
         msg = msg_ref[...]
-        onehot = _onehot(seg, seg >= 0, out_ref.shape[0]).astype(msg.dtype)
-        out_ref[...] += jax.lax.dot_general(
-            onehot,
-            msg,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=out_ref.dtype,
-        )
+        mask = member(seg_ref[...], out_ref.shape[0])
+        out_ref[...] += scatter_sum(onehot(mask, msg.dtype), msg)
 
 
 @functools.partial(
@@ -223,60 +130,66 @@ def segment_spmm_ragged_pallas(
     num_segments: int,
     *,
     block_edges: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """Ragged drop-in for :func:`segment_spmm_pallas` on a 1-D edge grid:
+    """out[s] = sum over e with seg[e] == s of msg[e], on a 1-D edge grid:
     the full output is a revisited block and all-padding edge tiles are
-    skipped via per-tile valid counts — the engine's power-of-two bucket
+    skipped via per-tile valid counts, so the engine's power-of-two bucket
     padding stops costing matmuls."""
     m, d = msg.shape
+    n = num_segments
     (msg, seg), m_pad = _pad_edges(
         [msg, seg.astype(jnp.int32)], m, block_edges, [0, -1]
     )
     counts = jnp.sum((seg >= 0).reshape(-1, block_edges), axis=1).astype(jnp.int32)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _spmm_ragged_kernel,
-        grid=(m_pad // block_edges,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda eb: (eb,)),
-            pl.BlockSpec((block_edges,), lambda eb: (eb,)),
-            pl.BlockSpec((block_edges, d), lambda eb: (eb, 0)),
-        ],
-        out_specs=pl.BlockSpec((num_segments, d), lambda eb: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_segments, d), msg.dtype),
-        interpret=interpret,
-    )(counts, seg, msg)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(m_pad // block_edges,),
+            in_specs=[
+                pl.BlockSpec((None, 1, block_edges), lambda eb, cnt: (eb, 0, 0)),
+                pl.BlockSpec((block_edges, d), lambda eb, cnt: (eb, 0)),
+            ],
+            out_specs=pl.BlockSpec((n, d), lambda eb, cnt: (0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
+        compiler_params=VMEM_LIMIT,
+        interpret=interpret_mode(interpret),
+    )(counts, edge_rows(seg, block_edges), msg)
+    return out.astype(msg.dtype)
 
 
 # -- one-pass GAT edge-softmax + aggregate -----------------------------------
 
 
-def _gat_kernel(seg_ref, logit_ref, msg_ref, acc_ref, m_ref, z_ref):
-    @pl.when(pl.program_id(0) == 0)
+def _gat_kernel(seg_ref, logit_ref, msg_ref, out_ref, m_ref, z_ref):
+    eb = pl.program_id(0)
+
+    @pl.when(eb == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         z_ref[...] = jnp.zeros_like(z_ref)
 
-    seg = seg_ref[...]
-    logit = logit_ref[...].astype(jnp.float32)  # [BM]
-    msg = msg_ref[...].astype(jnp.float32)  # [BM, D]
-    member = _onehot(seg, seg >= 0, acc_ref.shape[0])  # [BM, n] bool
-    tile_max = jnp.max(jnp.where(member, logit[:, None], _NEG_INF), axis=0)
-    m_prev = m_ref[...][:, 0]
+    mask = member(seg_ref[...], out_ref.shape[0])  # [n, BM]
+    logit = logit_ref[...].astype(jnp.float32)  # [1, BM]
+    tile_max = jnp.max(jnp.where(mask, logit, _NEG_INF), axis=1, keepdims=True)
+    m_prev = m_ref[...]  # [n, 1]
     m_new = jnp.maximum(m_prev, tile_max)
     # online-softmax rescale of the running sums (exp(0)=1 while a segment
     # is still empty; exp(-huge) underflows to 0 once a real max arrives)
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(jnp.where(member, logit[:, None] - m_new[None, :], _NEG_INF))
-    z_ref[...] = (alpha * z_ref[...][:, 0] + jnp.sum(p, axis=0))[:, None]
-    acc_ref[...] = alpha[:, None] * acc_ref[...] + jax.lax.dot_general(
-        p,
-        msg,
-        dimension_numbers=(((0,), (0,)), ((), ())),  # p^T @ msg
-        preferred_element_type=jnp.float32,
+    p = jnp.exp(jnp.where(mask, logit - m_new, _NEG_INF))  # [n, BM]
+    z_ref[...] = alpha * z_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    out_ref[...] = alpha * out_ref[...] + scatter_sum(
+        p, msg_ref[...].astype(jnp.float32)
     )
-    m_ref[...] = m_new[:, None]
+    m_ref[...] = m_new
+
+    @pl.when(eb == pl.num_programs(0) - 1)
+    def _normalize():
+        out_ref[...] = out_ref[...] / jnp.maximum(z_ref[...], 1e-9)
 
 
 @functools.partial(
@@ -289,42 +202,36 @@ def gat_softmax_aggregate_pallas(
     num_segments: int,
     *,
     block_edges: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """out[s] = sum_e softmax_{seg==s}(logits)[e] * msg[e] in ONE kernel.
 
     Replaces the 3-pass segment-max → exp/normalize → segment-sum sequence:
-    running (max, denominator, accumulator) live in revisited output blocks
-    and are rescaled flash-attention-style as edge tiles stream through.
-    Matches ``alpha = e / max(z, 1e-9)`` from ``_seg_softmax`` exactly, so
-    empty segments return 0."""
+    the running max and denominator live in VMEM scratch, the accumulator
+    in the revisited output block, rescaled flash-attention-style as edge
+    tiles stream through.  Matches ``alpha = e / max(z, 1e-9)`` from
+    ``_seg_softmax`` exactly, so empty segments return 0."""
     m = seg.shape[0]
     d = msg.shape[1]
     n = num_segments
     (seg, logits, msg), m_pad = _pad_edges(
         [seg.astype(jnp.int32), logits, msg], m, block_edges, [-1, 0, 0]
     )
-    acc, _, z = pl.pallas_call(
+    row = pl.BlockSpec((None, 1, block_edges), lambda eb: (eb, 0, 0))
+    out = pl.pallas_call(
         _gat_kernel,
         grid=(m_pad // block_edges,),
-        in_specs=[
-            pl.BlockSpec((block_edges,), lambda eb: (eb,)),
-            pl.BlockSpec((block_edges,), lambda eb: (eb,)),
-            pl.BlockSpec((block_edges, d), lambda eb: (eb, 0)),
+        in_specs=[row, row, pl.BlockSpec((block_edges, d), lambda eb: (eb, 0))],
+        out_specs=pl.BlockSpec((n, d), lambda eb: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((n, 1), jnp.float32),
+            pltpu.VMEM((n, 1), jnp.float32),
         ],
-        out_specs=[
-            pl.BlockSpec((n, d), lambda eb: (0, 0)),
-            pl.BlockSpec((n, 1), lambda eb: (0, 0)),
-            pl.BlockSpec((n, 1), lambda eb: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, d), jnp.float32),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(seg, logits, msg)
-    return (acc / jnp.maximum(z, 1e-9)).astype(msg.dtype)
+        compiler_params=VMEM_LIMIT,
+        interpret=interpret_mode(interpret),
+    )(edge_rows(seg, block_edges), edge_rows(logits, block_edges), msg)
+    return out.astype(msg.dtype)
 
 
 # -- segment max -------------------------------------------------------------
@@ -335,11 +242,10 @@ def _segmax_kernel(seg_ref, x_ref, out_ref):
     def _init():
         out_ref[...] = jnp.full_like(out_ref, _NEG_INF)
 
-    seg = seg_ref[...]
-    x = x_ref[...].astype(jnp.float32)
-    member = _onehot(seg, seg >= 0, out_ref.shape[0])
-    tile_max = jnp.max(jnp.where(member, x[:, None], _NEG_INF), axis=0)
-    out_ref[...] = jnp.maximum(out_ref[...], tile_max[:, None])
+    mask = member(seg_ref[...], out_ref.shape[0])  # [n, BM]
+    x = x_ref[...].astype(jnp.float32)  # [1, BM]
+    tile_max = jnp.max(jnp.where(mask, x, _NEG_INF), axis=1, keepdims=True)
+    out_ref[...] = jnp.maximum(out_ref[...], tile_max)
 
 
 @functools.partial(
@@ -351,24 +257,24 @@ def segment_max_pallas(
     num_segments: int,
     *,
     block_edges: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Per-segment max of x over seg (padding seg=-1 excluded); empty
     segments yield 0.0, matching ``_seg_softmax``'s finite-fix."""
     m = seg.shape[0]
+    n = num_segments
     (seg, x), m_pad = _pad_edges(
         [seg.astype(jnp.int32), x], m, block_edges, [-1, 0]
     )
+    row = pl.BlockSpec((None, 1, block_edges), lambda eb: (eb, 0, 0))
     out = pl.pallas_call(
         _segmax_kernel,
         grid=(m_pad // block_edges,),
-        in_specs=[
-            pl.BlockSpec((block_edges,), lambda eb: (eb,)),
-            pl.BlockSpec((block_edges,), lambda eb: (eb,)),
-        ],
-        out_specs=pl.BlockSpec((num_segments, 1), lambda eb: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_segments, 1), jnp.float32),
-        interpret=interpret,
-    )(seg, x)
+        in_specs=[row, row],
+        out_specs=pl.BlockSpec((n, 1), lambda eb: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
+        compiler_params=VMEM_LIMIT,
+        interpret=interpret_mode(interpret),
+    )(edge_rows(seg, block_edges), edge_rows(x, block_edges))
     mx = out[:, 0]
     return jnp.where(mx > _NEG_INF * 0.5, mx, 0.0).astype(x.dtype)

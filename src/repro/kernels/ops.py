@@ -1,13 +1,13 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``INTERPRET`` defaults to True (this box is CPU; the kernels execute in
-Pallas interpret mode).  On a real TPU set REPRO_PALLAS_INTERPRET=0.
-Every wrapper has a matching pure-jnp oracle in ref.py, and tests assert
-allclose across shape/dtype sweeps.
+Each wrapper leaves ``interpret`` unset, so the kernel picks its mode from
+the platform when it is traced (:func:`repro.kernels.mode.interpret_mode`):
+Mosaic-compiled on a TPU, where a kernel that does not compile raises, and
+Pallas interpret mode everywhere else.  Every wrapper has a matching
+pure-jnp oracle in ref.py, and tests assert allclose across shape/dtype
+sweeps.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -16,8 +16,6 @@ from repro.kernels.autotune import DEFAULT_CONFIG, get_tuned
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.fused_gnn import (
     gat_softmax_aggregate_pallas,
-    gather_spmm_pallas,
-    gather_spmm_ragged_pallas,
     segment_max_pallas,
     segment_spmm_ragged_pallas,
 )
@@ -32,10 +30,7 @@ from repro.kernels.ref import (
 from repro.kernels.segment_spmm import segment_spmm_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-
 __all__ = [
-    "INTERPRET",
     "gnn_aggregate",
     "gnn_gather_aggregate",
     "gnn_gat_aggregate",
@@ -44,8 +39,6 @@ __all__ = [
     "ssd_scan",
     "segment_spmm_pallas",
     "segment_spmm_ragged_pallas",
-    "gather_spmm_pallas",
-    "gather_spmm_ragged_pallas",
     "gat_softmax_aggregate_pallas",
     "segment_max_pallas",
     "flash_attention_pallas",
@@ -87,12 +80,10 @@ def gnn_aggregate(
     shape = (msg.shape[0], num_segments, msg.shape[1])
     if ragged:
         _, be = _blocks("segment_spmm_ragged", shape, msg.dtype, None, block_edges)
-        return segment_spmm_ragged_pallas(
-            msg, seg, num_segments, block_edges=be, interpret=INTERPRET
-        )
+        return segment_spmm_ragged_pallas(msg, seg, num_segments, block_edges=be)
     br, be = _blocks("segment_spmm", shape, msg.dtype, block_rows, block_edges)
     return segment_spmm_pallas(
-        msg, seg, num_segments, block_rows=br, block_edges=be, interpret=INTERPRET
+        msg, seg, num_segments, block_rows=br, block_edges=be
     )
 
 
@@ -104,17 +95,19 @@ def gnn_gather_aggregate(
     *,
     use_kernel: bool = True,
     block_edges: int | None = None,
-    ragged: bool = True,
 ) -> jax.Array:
-    """Fused gather+aggregate: out[s] = sum_{seg[e]==s} feats[idx[e]],
-    without materializing the [E, D] message array."""
+    """Gather + aggregate: out[s] = sum_{seg[e]==s} feats[idx[e]].
+
+    The gather is XLA's (a row gather inside a Mosaic kernel does not
+    compile for the TPU); the aggregation is the ragged segment kernel.
+    Edges with ``idx == -1`` or ``seg == -1`` are padding."""
     if not use_kernel:
         return gather_spmm_ref(feats, idx, seg, num_segments)
-    shape = (idx.shape[0], num_segments, feats.shape[1])
-    op = "gather_spmm_ragged" if ragged else "gather_spmm"
-    _, be = _blocks(op, shape, feats.dtype, None, block_edges)
-    fn = gather_spmm_ragged_pallas if ragged else gather_spmm_pallas
-    return fn(feats, idx, seg, num_segments, block_edges=be, interpret=INTERPRET)
+    ok = (idx >= 0) & (seg >= 0)
+    msg = jnp.where(ok[:, None], feats[jnp.maximum(idx, 0)], 0)
+    return gnn_aggregate(
+        msg, jnp.where(ok, seg, -1), num_segments, block_edges=block_edges
+    )
 
 
 def gnn_gat_aggregate(
@@ -132,7 +125,7 @@ def gnn_gat_aggregate(
     shape = (seg.shape[0], num_segments, msg.shape[1])
     _, be = _blocks("gat_softmax_aggregate", shape, msg.dtype, None, block_edges)
     return gat_softmax_aggregate_pallas(
-        logits, msg, seg, num_segments, block_edges=be, interpret=INTERPRET
+        logits, msg, seg, num_segments, block_edges=be
     )
 
 
@@ -149,9 +142,7 @@ def gnn_segment_max(
         return segment_max_ref(x, seg, num_segments)
     shape = (seg.shape[0], num_segments, 1)
     _, be = _blocks("segment_max", shape, x.dtype, None, block_edges)
-    return segment_max_pallas(
-        x, seg, num_segments, block_edges=be, interpret=INTERPRET
-    )
+    return segment_max_pallas(x, seg, num_segments, block_edges=be)
 
 
 def mha_attention(
@@ -176,8 +167,6 @@ def mha_attention(
     vh = vr.transpose(0, 2, 1, 3).reshape(b * h, -1, d)
     fn = flash_attention_pallas if use_kernel else attention_ref
     kwargs = dict(causal=causal, window=window, kv_offset=kv_offset)
-    if use_kernel:
-        kwargs["interpret"] = INTERPRET
     out = jax.vmap(lambda qq, kk, vv: fn(qq, kk, vv, **kwargs))(qh, kh, vh)
     return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
 
@@ -205,7 +194,7 @@ def ssd_scan(
     a = dt * A[None, None, :]  # [B, S, H]
 
     def one(xx, aa, dd, bb, cc):
-        y, _ = ssd_scan_pallas(xx, aa, dd, bb, cc, chunk=chunk, interpret=INTERPRET)
+        y, _ = ssd_scan_pallas(xx, aa, dd, bb, cc, chunk=chunk)
         return y
 
     # flatten (batch, head)

@@ -8,7 +8,6 @@ __all__ = [
     "segment_spmm_ref",
     "segment_spmm_ragged_ref",
     "gather_spmm_ref",
-    "gather_spmm_ragged_ref",
     "gat_softmax_aggregate_ref",
     "segment_max_ref",
     "attention_ref",
@@ -37,19 +36,13 @@ def gather_spmm_ref(
     feats: jax.Array, idx: jax.Array, seg: jax.Array, num_segments: int
 ) -> jax.Array:
     """out[s] = sum_{e: seg[e]==s} feats[idx[e]]; edges with idx or seg
-    equal to -1 are dropped (the fused kernel's padding convention)."""
+    equal to -1 are dropped (``gnn_gather_aggregate``'s padding
+    convention)."""
     ok = (idx >= 0) & (seg >= 0)
     msg = jnp.where(ok[:, None], feats[jnp.maximum(idx, 0)], 0)
     return jax.ops.segment_sum(
         msg, jnp.maximum(seg, 0), num_segments=num_segments
     )
-
-
-def gather_spmm_ragged_ref(
-    feats: jax.Array, idx: jax.Array, seg: jax.Array, num_segments: int
-) -> jax.Array:
-    """Ragged tile-skipping changes nothing semantically."""
-    return gather_spmm_ref(feats, idx, seg, num_segments)
 
 
 def segment_max_ref(x: jax.Array, seg: jax.Array, num_segments: int) -> jax.Array:

@@ -6,17 +6,18 @@ destination rows (``out[seg[e]] += msg[e]``).  On GPU this is a scatter-add
 adapt the paper's aggregation hotspot TPU-natively (DESIGN.md §3):
 
     the scatter becomes a block-tiled ONE-HOT MATMUL.  For an edge tile of
-    BM messages and a row tile of BN segments, ``onehot[bm, bn] =
-    (seg[bm] == row_ids[bn])`` and ``out_tile += onehot^T @ msg_tile`` —
+    BM messages and a row tile of BN segments, ``onehot[bn, bm] =
+    (row_ids[bn] == seg[bm])`` and ``out_tile += onehot @ msg_tile`` —
     a (BN × BM) · (BM × D) MXU contraction entirely in VMEM.
 
 Grid is (row_blocks, edge_blocks) with the edge axis innermost; the output
 tile is accumulated across the inner axis (revisited output block), written
 once zeroed at the first edge block.  ``seg`` must be sorted ascending for
 efficiency claims but correctness holds for any order.  Padding rows use
-``seg = -1`` (matches no row).
+``seg = -1`` (matches no row).  Segment ids are read in the 2-D
+``(tiles, 1, BM)`` layout of ``fused_gnn.edge_rows``.
 
-VMEM budget per step: BM·D (msg) + BN·D (out) + BM·BN (onehot) floats —
+VMEM budget per step: BM·D (msg) + BN·D (out) + BN·BM (onehot) floats —
 default BM=BN=128, D tiles of 128..512 keep it well under 16 MiB.
 """
 from __future__ import annotations
@@ -27,28 +28,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.fused_gnn import edge_rows, member, onehot, scatter_sum
+from repro.kernels.mode import interpret_mode
+
 __all__ = ["segment_spmm_pallas"]
 
 
-def _kernel(seg_ref, msg_ref, out_ref, *, block_rows: int):
-    eb = pl.program_id(1)
-
-    @pl.when(eb == 0)
+def _kernel(seg_ref, msg_ref, out_ref):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    rb = pl.program_id(0)
-    seg = seg_ref[...]  # [BM] int32 (global segment ids, -1 = padding)
-    msg = msg_ref[...]  # [BM, D]
-    row_base = rb * block_rows
-    row_ids = row_base + jax.lax.iota(jnp.int32, block_rows)  # [BN]
-    onehot = (seg[:, None] == row_ids[None, :]).astype(msg.dtype)  # [BM, BN]
-    out_ref[...] += jax.lax.dot_general(
-        onehot,
-        msg,
-        dimension_numbers=(((0,), (0,)), ((), ())),  # onehot^T @ msg
-        preferred_element_type=out_ref.dtype,
-    )
+    block_rows = out_ref.shape[0]
+    msg = msg_ref[...]
+    mask = member(seg_ref[...], block_rows, base=pl.program_id(0) * block_rows)
+    out_ref[...] += scatter_sum(onehot(mask, msg.dtype), msg)
 
 
 @functools.partial(
@@ -61,29 +55,31 @@ def segment_spmm_pallas(
     *,
     block_rows: int = 128,
     block_edges: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """out[s] = sum over e with seg[e] == s of msg[e].
 
     msg: [M, D] (M padded to block_edges), seg: [M] int32 (-1 padding).
     num_segments is padded up to block_rows internally; callers slice."""
     m, d = msg.shape
-    assert seg.shape == (m,)
-    m_pad = -(-m // block_edges) * block_edges
+    if seg.shape != (m,):
+        raise ValueError(f"seg shape {seg.shape} does not match msg rows {m}")
+    m_pad = -(-max(m, 1) // block_edges) * block_edges
     n_pad = -(-num_segments // block_rows) * block_rows
+    seg = seg.astype(jnp.int32)
     if m_pad != m:
         msg = jnp.pad(msg, ((0, m_pad - m), (0, 0)))
         seg = jnp.pad(seg, (0, m_pad - m), constant_values=-1)
     grid = (n_pad // block_rows, m_pad // block_edges)
     out = pl.pallas_call(
-        functools.partial(_kernel, block_rows=block_rows),
+        _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_edges,), lambda rb, eb: (eb,)),
+            pl.BlockSpec((None, 1, block_edges), lambda rb, eb: (eb, 0, 0)),
             pl.BlockSpec((block_edges, d), lambda rb, eb: (eb, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda rb, eb: (rb, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, d), msg.dtype),
-        interpret=interpret,
-    )(seg.astype(jnp.int32), msg)
-    return out[:num_segments]
+        out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
+        interpret=interpret_mode(interpret),
+    )(edge_rows(seg, block_edges), msg)
+    return out[:num_segments].astype(msg.dtype)

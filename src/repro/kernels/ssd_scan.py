@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.mode import interpret_mode
+
 __all__ = ["ssd_scan_pallas"]
 
 
@@ -70,7 +72,7 @@ def ssd_scan_pallas(
     C: jax.Array,  # [S, N]
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (y [S, P], final_state [P, N])."""
     S, P = x.shape
@@ -102,6 +104,6 @@ def ssd_scan_pallas(
             jax.ShapeDtypeStruct((s_pad, P), x.dtype),
             jax.ShapeDtypeStruct((P, N), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(a[:, None], dt[:, None], x, B, C)
     return y[:S], state

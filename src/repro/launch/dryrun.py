@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config
-from repro.launch.mesh import HW, make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.launch.shardings import (
     batch_specs,
     cache_specs,
@@ -128,7 +128,10 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str, verbose=T
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
     coll = parse_collectives(compiled.as_text())
-    rf = roofline(cfg, shape_name, dict(mesh.shape), num_chips, cost, coll)
+    rf = roofline(
+        cfg, shape_name, dict(mesh.shape), num_chips, cost, coll,
+        PRODUCTION_DEVICE_KIND,
+    )
 
     result = {
         "arch": arch,

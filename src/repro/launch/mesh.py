@@ -9,15 +9,42 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_local_mesh", "HW"]
+__all__ = [
+    "make_production_mesh",
+    "make_local_mesh",
+    "PEAKS",
+    "PRODUCTION_DEVICE_KIND",
+    "peaks",
+]
 
 
-# TPU v5e hardware constants used by the roofline analysis
-HW = {
-    "peak_flops_bf16": 197e12,  # per chip
-    "hbm_bw": 819e9,  # bytes/s per chip
-    "ici_bw": 50e9,  # bytes/s per link
+# Published per-chip peak rates for the roofline analysis, keyed by
+# ``jax.Device.device_kind``.  Source: Google Cloud documentation, "TPU
+# v5e" (system architecture): 197 TFLOP/s bf16, 819 GB/s of HBM
+# bandwidth, 1,600 Gbit/s of interchip interconnect per chip.
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops_bf16": 197e12,
+        "hbm_bw": 819e9,  # bytes/s
+        "ici_bw": 1600e9 / 8,  # bytes/s, all links together
+    },
 }
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak rates of one chip of ``device_kind``; a device without
+    published peaks in :data:`PEAKS` is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak rates for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
+
+
+# the chips of make_production_mesh, as jax names their device_kind
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -21,7 +21,7 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass
 
-from repro.launch.mesh import HW
+from repro.launch.mesh import peaks
 from repro.launch.specs import SHAPES
 from repro.models.transformer.config import ArchConfig
 
@@ -318,7 +318,7 @@ def parse_collectives(hlo_text: str) -> dict:
 # ---------------------------------------------------------------------------
 #
 # Shape dict keys: edges E (padded), segments N, dim D, and optionally
-# feat_rows F (gather ops, default N), valid_edges Ev (ragged ops, default
+# valid_edges Ev (ragged ops, default
 # E; padding assumed to be a suffix as the engine lays it out), block_rows
 # BN / block_edges BM (default 128), dtype_bytes b (default 4).
 #
@@ -327,9 +327,8 @@ def parse_collectives(hlo_text: str) -> dict:
 # hardware fraction even though most one-hot entries are zero.  Bytes model
 # HBM traffic under the kernels' actual block residency:
 #   * segment_spmm (2-D grid) re-reads each edge tile once per ROW block;
-#   * the fused/ragged 1-D-grid kernels keep the output resident and read
-#     each edge tile once — and gather_spmm never materializes the [E, D]
-#     message array at all (that round trip is the fusion win);
+#   * the 1-D-grid kernels keep the output resident and read each edge
+#     tile once;
 #   * ragged variants only touch the ~ceil(Ev/BM) non-empty tiles.
 
 
@@ -337,33 +336,29 @@ def _kshape(shape: dict) -> tuple:
     e = float(shape["edges"])
     n = float(shape["segments"])
     d = float(shape["dim"])
-    f = float(shape.get("feat_rows", n))
     ev = float(shape.get("valid_edges", e))
     bn = float(shape.get("block_rows", 128))
     bm = float(shape.get("block_edges", 128))
     b = float(shape.get("dtype_bytes", 4))
     tiles = -(-e // bm)  # total edge tiles
     active = min(tiles, -(-ev // bm)) if ev > 0 else 0.0  # non-empty tiles
-    return e, n, d, f, ev, bn, bm, b, tiles, active
+    return e, n, d, ev, bn, bm, b, tiles, active
 
 
 KERNEL_OPS = (
     "segment_spmm",
     "segment_spmm_ragged",
-    "gather_spmm",
-    "gather_spmm_ragged",
     "gat_softmax_aggregate",
     "segment_max",
-    "unfused_gather_spmm",  # gather -> segment_spmm sequence, for comparison
 )
 
 
 def kernel_flops(op: str, shape: dict) -> float:
-    e, n, d, f, ev, bn, bm, b, tiles, active = _kshape(shape)
+    e, n, d, ev, bn, bm, b, tiles, active = _kshape(shape)
     matmul = 2.0 * n * d  # per edge row fed to the MXU
-    if op in ("segment_spmm", "gather_spmm", "unfused_gather_spmm"):
+    if op == "segment_spmm":
         return e * matmul
-    if op in ("segment_spmm_ragged", "gather_spmm_ragged"):
+    if op == "segment_spmm_ragged":
         return active * bm * matmul
     if op == "gat_softmax_aggregate":
         # matmul + membership/max/exp/rescale vector work per (edge, row)
@@ -374,7 +369,7 @@ def kernel_flops(op: str, shape: dict) -> float:
 
 
 def kernel_hbm_bytes(op: str, shape: dict) -> float:
-    e, n, d, f, ev, bn, bm, b, tiles, active = _kshape(shape)
+    e, n, d, ev, bn, bm, b, tiles, active = _kshape(shape)
     row_blocks = -(-n // bn)
     out = n * d * b
     if op == "segment_spmm":
@@ -382,35 +377,29 @@ def kernel_hbm_bytes(op: str, shape: dict) -> float:
         return row_blocks * e * (d * b + 4) + out
     if op == "segment_spmm_ragged":
         return active * bm * (d * b + 4) + 4 * tiles + out
-    if op == "gather_spmm":
-        return f * d * b + e * 8 + out
-    if op == "gather_spmm_ragged":
-        return f * d * b + e * 8 + 4 * tiles + out
     if op == "gat_softmax_aggregate":
         return e * (d * b + b + 4) + n * (d + 2) * 4
     if op == "segment_max":
         return e * (b + 4) + n * 4
-    if op == "unfused_gather_spmm":
-        # gather: feats read + [E, D] msg write; spmm: msg+seg re-read per
-        # row block; out write.  The msg round trip is what fusion deletes.
-        return f * d * b + e * d * b + row_blocks * e * (d * b + 4) + out
     raise ValueError(f"unknown kernel op {op!r}")
 
 
-def kernel_roofline(op: str, shape: dict, wall_s: float, dtype: str = "f32") -> dict:
-    """Achieved-vs-peak for one measured kernel wall-clock.
+def kernel_roofline(
+    op: str, shape: dict, wall_s: float, device_kind: str, dtype: str = "f32"
+) -> dict:
+    """Achieved-vs-peak for one kernel wall-clock measured on a chip of
+    ``device_kind`` (whose published peaks must be in ``mesh.PEAKS``).
 
     Peak FLOP/s follows the dtype (the MXU's f32 rate is half its bf16
     rate); ``bound`` names the limiting resource at these shapes and
-    ``frac_of_*`` are the honest hardware fractions ``benchmarks/kernels.py``
-    reports.  In interpret mode wall-clock is Python-loop dominated, so the
-    fractions are only meaningful on a real TPU — the analytic terms and the
-    ``bound_s`` floor are hardware truths either way."""
+    ``frac_of_*`` are the hardware fractions ``benchmarks/kernels.py``
+    reports."""
+    hw = peaks(device_kind)
     fl = kernel_flops(op, shape)
     by = kernel_hbm_bytes(op, shape)
-    peak = HW["peak_flops_bf16"] * (0.5 if dtype in ("f32", "float32") else 1.0)
+    peak = hw["peak_flops_bf16"] * (0.5 if dtype in ("f32", "float32") else 1.0)
     compute_s = fl / peak
-    memory_s = by / HW["hbm_bw"]
+    memory_s = by / hw["hbm_bw"]
     bound_s = max(compute_s, memory_s)
     achieved_flops = fl / wall_s if wall_s > 0 else 0.0
     achieved_bw = by / wall_s if wall_s > 0 else 0.0
@@ -427,7 +416,7 @@ def kernel_roofline(op: str, shape: dict, wall_s: float, dtype: str = "f32") -> 
         "achieved_flops_per_s": achieved_flops,
         "frac_of_peak_flops": achieved_flops / peak if peak else 0.0,
         "achieved_bytes_per_s": achieved_bw,
-        "frac_of_hbm_bw": achieved_bw / HW["hbm_bw"],
+        "frac_of_hbm_bw": achieved_bw / hw["hbm_bw"],
         "frac_of_bound": bound_s / wall_s if wall_s > 0 else 0.0,
     }
 
@@ -444,16 +433,20 @@ def roofline(
     num_chips: int,
     cost: dict,
     coll: dict,
+    device_kind: str,
 ) -> dict:
+    """Roofline terms per chip of ``device_kind`` (an entry of
+    ``mesh.PEAKS``)."""
+    hw = peaks(device_kind)
     fl = analytic_flops(cfg, shape_name)
     flops_dev = fl["total"] / num_chips
     hlo_flops_dev = float(cost.get("flops", 0.0))
     hlo_bytes_dev = float(cost.get("bytes accessed", 0.0))
     analytic_bytes = analytic_hbm_bytes(cfg, shape_name, mesh_shape)
     bytes_dev = max(hlo_bytes_dev, analytic_bytes)
-    compute_s = flops_dev / HW["peak_flops_bf16"]
-    memory_s = bytes_dev / HW["hbm_bw"]
-    collective_s = coll["total_bytes"] / HW["ici_bw"]
+    compute_s = flops_dev / hw["peak_flops_bf16"]
+    memory_s = bytes_dev / hw["hbm_bw"]
+    collective_s = coll["total_bytes"] / hw["ici_bw"]
     terms = {"compute_s": compute_s, "memory_s": memory_s, "collective_s": collective_s}
     dominant = max(terms, key=terms.get)
     return {

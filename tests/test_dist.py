@@ -9,10 +9,7 @@ import time
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.api import GLISPConfig, GLISPSystem
 from repro.core.faults import FaultPlan, FaultSpec, RetryPolicy

@@ -15,10 +15,7 @@ import time
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.faults import (
     CircuitBreaker,

@@ -1,4 +1,5 @@
-"""Fused GNN kernels vs their jnp oracles + the deterministic autotuner.
+"""GNN kernels and dispatchers vs their jnp oracles + the deterministic
+autotuner.
 
 Property sweeps cover ragged edge counts (padding tails of every length,
 including all-padding and zero-edge inputs), both dtypes the engine
@@ -11,19 +12,15 @@ import json
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:  # minimal envs: deterministic fallback shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import autotune as at
 from repro.kernels.fused_gnn import (
     gat_softmax_aggregate_pallas,
-    gather_spmm_pallas,
-    gather_spmm_ragged_pallas,
     segment_max_pallas,
     segment_spmm_ragged_pallas,
 )
+from repro.kernels.ops import gnn_gather_aggregate
 from repro.kernels.ref import (
     gat_softmax_aggregate_ref,
     gather_spmm_ref,
@@ -75,8 +72,7 @@ def test_property_gather_spmm(m, n, d, frac, seed):
     valid = int(m * frac)
     idx, seg, feats, _, _ = _edges(m, n, valid, seed, d=d)
     want = gather_spmm_ref(feats, idx, seg, n)
-    _close(gather_spmm_pallas(feats, idx, seg, n, block_edges=32), want)
-    _close(gather_spmm_ragged_pallas(feats, idx, seg, n, block_edges=32), want)
+    _close(gnn_gather_aggregate(feats, idx, seg, n, block_edges=32), want)
 
 
 @settings(max_examples=12, deadline=None)
@@ -136,13 +132,12 @@ def test_fused_kernels_dtypes(dtype):
     m, n, d = 200, 48, 16
     idx, seg, feats, msg, logits = _edges(m, n, 150, seed=7, d=d, dtype=dtype)
     _close(
-        gather_spmm_pallas(feats, idx, seg, n), gather_spmm_ref(feats, idx, seg, n),
+        gnn_gather_aggregate(feats, idx, seg, n),
+        gather_spmm_ref(feats, idx, seg, n),
         dtype,
     )
     _close(
-        gather_spmm_ragged_pallas(feats, idx, seg, n),
-        gather_spmm_ref(feats, idx, seg, n),
-        dtype,
+        segment_spmm_ragged_pallas(msg, seg, n), segment_spmm_ref(msg, seg, n), dtype
     )
     _close(
         gat_softmax_aggregate_pallas(logits, msg, seg, n),
@@ -160,9 +155,9 @@ def test_fused_kernels_dtypes(dtype):
 def test_all_padding_tiles_produce_zeros():
     n, d = 12, 8
     idx, seg, feats, msg, logits = _edges(96, n, 0, seed=3, d=d)
-    assert not np.asarray(gather_spmm_pallas(feats, idx, seg, n, block_edges=32)).any()
+    assert not np.asarray(gnn_gather_aggregate(feats, idx, seg, n, block_edges=32)).any()
     assert not np.asarray(
-        gather_spmm_ragged_pallas(feats, idx, seg, n, block_edges=32)
+        segment_spmm_ragged_pallas(msg, seg, n, block_edges=32)
     ).any()
     assert not np.asarray(
         gat_softmax_aggregate_pallas(logits, msg, seg, n, block_edges=32)
@@ -178,7 +173,7 @@ def test_zero_edge_input():
     feats = jnp.ones((n, d), jnp.float32)
     empty_i = jnp.zeros((0,), jnp.int32)
     empty_f = jnp.zeros((0, d), jnp.float32)
-    out = gather_spmm_pallas(feats, empty_i, empty_i, n)
+    out = gnn_gather_aggregate(feats, empty_i, empty_i, n)
     assert out.shape == (n, d) and not np.asarray(out).any()
     out = gat_softmax_aggregate_pallas(
         jnp.zeros((0,), jnp.float32), empty_f, empty_i, n
@@ -191,7 +186,7 @@ def test_segment_with_no_edges_stays_zero():
     seg = jnp.array([0, 0, 2, -1], jnp.int32)
     idx = jnp.array([1, 2, 0, -1], jnp.int32)
     feats = jnp.arange(12, dtype=jnp.float32).reshape(3, 4)
-    out = np.asarray(gather_spmm_pallas(feats, idx, seg, 3))
+    out = np.asarray(gnn_gather_aggregate(feats, idx, seg, 3))
     np.testing.assert_array_equal(out[1], np.zeros(4))
     np.testing.assert_allclose(out, np.asarray(gather_spmm_ref(feats, idx, seg, 3)))
 
@@ -210,15 +205,16 @@ def _clean_tuner():
 
 def test_autotune_same_inputs_same_config(tmp_path):
     shape = (256, 64, 16)
-    cfg1 = at.autotune("gather_spmm", shape, jnp.float32, cache_dir=str(tmp_path))
+    op = "segment_spmm_ragged"
+    cfg1 = at.autotune(op, shape, jnp.float32, cache_dir=str(tmp_path))
     assert at.stats()["measured"] == 1
-    cfg2 = at.autotune("gather_spmm", shape, jnp.float32, cache_dir=str(tmp_path))
+    cfg2 = at.autotune(op, shape, jnp.float32, cache_dir=str(tmp_path))
     assert cfg2 == cfg1 and at.stats()["memory_hits"] == 1
     assert at.stats()["measured"] == 1  # no re-sweep
     # the chosen config is from the fixed candidate grid
-    assert cfg1 in at.CANDIDATES["gather_spmm"]
+    assert cfg1 in at.CANDIDATES[op]
     # and ops.py can now resolve it for any shape in the same pow2 bucket
-    assert at.get_tuned("gather_spmm", (200, 50, 12), jnp.float32) == cfg1
+    assert at.get_tuned(op, (200, 50, 12), jnp.float32) == cfg1
 
 
 def test_autotune_artifact_roundtrip(tmp_path):
@@ -238,11 +234,12 @@ def test_autotune_artifact_roundtrip(tmp_path):
 
 
 def test_autotune_key_buckets_pow2():
-    k1 = at.tuned_key("gather_spmm", (200, 50, 12), jnp.float32)
-    k2 = at.tuned_key("gather_spmm", (256, 64, 16), jnp.float32)
-    assert k1 == k2 == "gather_spmm/256x64x16/float32"
-    assert at.tuned_key("gather_spmm", (300, 50, 12), jnp.float32) != k1
-    assert at.tuned_key("gather_spmm", (200, 50, 12), jnp.bfloat16) != k1
+    op = "segment_spmm_ragged"
+    k1 = at.tuned_key(op, (200, 50, 12), jnp.float32)
+    k2 = at.tuned_key(op, (256, 64, 16), jnp.float32)
+    assert k1 == k2 == "segment_spmm_ragged/256x64x16/float32"
+    assert at.tuned_key(op, (300, 50, 12), jnp.float32) != k1
+    assert at.tuned_key(op, (200, 50, 12), jnp.bfloat16) != k1
 
 
 def test_autotune_unknown_op_raises():

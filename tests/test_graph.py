@@ -1,10 +1,7 @@
 """Graph substrate: partition structure invariants, queries, persistence."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:  # minimal envs: deterministic fallback shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.partition import random_edge_partition
 from repro.graph import GraphPartition, build_partitions, power_law_graph

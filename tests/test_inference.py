@@ -3,10 +3,7 @@ reorder effect on chunk reads, bucketed-vs-reference engine equivalence, and
 the CSR-offset gather property."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:  # minimal envs: deterministic fallback shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.inference import (
     ChunkedEmbeddingStore,

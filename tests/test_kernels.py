@@ -4,10 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:  # minimal envs: deterministic fallback shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.ref import attention_ref, segment_spmm_ref, ssd_scan_ref

@@ -87,10 +87,13 @@ ENTRY %main (a: f32[128]) -> f32[128] {
 
 
 def test_mesh_constants():
-    from repro.launch.mesh import HW
+    from repro.launch.mesh import PRODUCTION_DEVICE_KIND, peaks
 
-    assert HW["peak_flops_bf16"] == 197e12
-    assert HW["hbm_bw"] == 819e9
+    hw = peaks(PRODUCTION_DEVICE_KIND)
+    assert hw["peak_flops_bf16"] == 197e12
+    assert hw["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="no published peak rates"):
+        peaks("cpu")
 
 
 def test_long_context_resolution():

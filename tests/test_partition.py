@@ -6,10 +6,7 @@ import functools
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - minimal environments
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.partition import (
     PARTITIONERS,

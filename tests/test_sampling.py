@@ -2,10 +2,7 @@
 load-balance accounting."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:  # minimal envs: deterministic fallback shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from scipy.stats import chisquare
 
 from repro.core.sampling import EdgeCutClient, SamplingServer

@@ -13,10 +13,7 @@ import time
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.serve import ContinuousBatcher, P2Quantile, RequestQueue, ServeRequest
 

@@ -3,10 +3,7 @@
 depth), SamplingSpec + config validation, service-level stats aggregation."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:  # minimal envs: deterministic fallback shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.api import (
     GLISPConfig,

@@ -6,10 +6,7 @@ import os
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:  # minimal envs: deterministic fallback shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.inference import (
     ChunkedEmbeddingStore,
