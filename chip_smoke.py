@@ -43,10 +43,18 @@ SERVE_REQUESTS = 48
 TOL_VS_F32 = 2e-2
 # Two runs on the chip at the same precision whose sums differ only in
 # order (XLA's segment_sum vs the Pallas kernel's one-hot matmul at
-# HIGHEST; a different batch or shard layout): a last-bit difference can
-# flip the bf16 rounding of one operand of the next matmul, 2**-8 of that
-# operand's term.  Allow 1e-2.
+# HIGHEST): a last-bit difference can flip the bf16 rounding of one
+# operand of the next matmul, 2**-8 of that operand's term, on any row of
+# the store.  Allow 1e-2.
 TOL_SAME_PRECISION = 1e-2
+# The data-parallel step against its single-device twin on the same
+# stacked batches: the same program but for the placement, so the batch-
+# mean losses differ only by the order of the cross-shard reduction.
+# Readings: 8.12e-8 on four v5e chips at full size; 8.05e-8 on 4 host
+# CPU devices at 6,000 vertices, where a sharded step that zeroed shard
+# 3's features read 1.76e-2 and one that took shard 0's batch in place of
+# shard 3's read 1.15e-2.  Allow 1e-5.
+TOL_DP_LOSS = 1e-5
 
 
 def log(msg: str) -> None:
@@ -215,8 +223,8 @@ def infer_and_serve_phase(system, g, model, params, workdir: str) -> None:
     check("served vs final-layer store", rel_diff(np.concatenate(got), np.concatenate(want)), TOL_SAME_PRECISION)
 
 
-def one_chip(args) -> None:
-    g, system = build_system(args.vertices)
+def one_chip(vertices: int = FULL_VERTICES) -> None:
+    g, system = build_system(vertices)
     model = sage_model()
     with system:
         trainer = train_phase(system, g, model)
@@ -224,12 +232,12 @@ def one_chip(args) -> None:
             infer_and_serve_phase(system, g, model, trainer.params, workdir)
 
 
-def four_chips(args) -> None:
+def four_chips(vertices: int = FULL_VERTICES) -> None:
     """Data-parallel training on a mesh over every device, checked against
     the unsharded single-device step on the same stacked batches."""
     import jax
 
-    g, system = build_system(args.vertices)
+    g, system = build_system(vertices)
     train_ids = np.random.default_rng(0).permutation(g.num_vertices)[
         : TRAIN_STEPS * BATCH
     ]
@@ -249,7 +257,7 @@ def four_chips(args) -> None:
             raise AssertionError(f"expected all {n} devices in both shardings")
         if len(dplog.losses) != TRAIN_STEPS // 2 or not np.all(np.isfinite(dplog.losses)):
             raise AssertionError(f"bad sharded losses {dplog.losses}")
-        check("sharded vs single-device losses", rel_diff(dplog.losses, dplog.ref_losses), TOL_SAME_PRECISION)
+        check("sharded vs single-device losses", rel_diff(dplog.losses, dplog.ref_losses), TOL_DP_LOSS)
 
 
 def main(argv=None) -> int:
@@ -259,15 +267,7 @@ def main(argv=None) -> int:
         action="store_true",
         help="run only data-parallel training on a 4-device mesh",
     )
-    ap.add_argument(
-        "--vertices",
-        type=int,
-        default=FULL_VERTICES,
-        help="cut the graph to this many vertices (default: full size)",
-    )
     args = ap.parse_args(argv)
-    if args.vertices < TRAIN_STEPS * BATCH:
-        ap.error(f"--vertices must be at least {TRAIN_STEPS * BATCH} (one epoch of seeds)")
 
     info = device_info()
     log(f"device: {json.dumps(info)}")
@@ -282,7 +282,7 @@ def main(argv=None) -> int:
 
     log(f"compile cache: {setup_compile_cache()}")
     t0 = time.perf_counter()
-    (four_chips if args.four_chips else one_chip)(args)
+    (four_chips if args.four_chips else one_chip)()
     log(f"all phases passed in {time.perf_counter() - t0:.1f}s")
     print(json.dumps({"ok": True, "device": info}), flush=True)
     return 0

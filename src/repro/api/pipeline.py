@@ -80,7 +80,6 @@ class BatchPipeline:
         prefetch: int = 2,
         inflight: int = 1,
         workers: str = "auto",  # auto | process | thread
-        worker_cores: tuple | None = None,  # CPU affinity for process workers
         seed: int = 0,
         partition_of: np.ndarray | None = None,
         balance_partitions: bool = False,
@@ -150,7 +149,6 @@ class BatchPipeline:
             if workers == "auto"
             else workers
         )
-        self.worker_cores = worker_cores
         self.vertex_quantum = vertex_quantum
         self.edge_quantum = edge_quantum
         # the training-side feature path: any FeatureSource (e.g. a
@@ -318,13 +316,6 @@ class BatchPipeline:
 
     # -- process-mode plumbing -----------------------------------------
     def _worker_loop(self):  # runs in the forked child: numpy only, no XLA
-        if self.worker_cores and hasattr(os, "sched_setaffinity"):
-            try:
-                # dedicate host cores to sampling (the consumer keeps the
-                # device cores), like dataloader-worker pinning in DGL
-                os.sched_setaffinity(0, set(self.worker_cores))
-            except OSError:
-                pass
         while True:
             # glint: disable=PRJ004 -- SimpleQueue has no timeout kwarg; an
             # idle worker is stopped via close(), which escalates to kill()

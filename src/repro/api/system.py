@@ -268,7 +268,6 @@ class GLISPSystem:
         opt=None,
         batch_size: int | None = None,
         prefetch: int | None = None,
-        worker_cores: tuple | None = None,
         spec=None,
         inflight: int | None = None,
         feature_source=None,
@@ -290,7 +289,6 @@ class GLISPSystem:
             seed=cfg.seed,
             prefetch=prefetch if prefetch is not None else cfg.prefetch,
             inflight=inflight if inflight is not None else cfg.inflight,
-            worker_cores=worker_cores,
             partition_of=(
                 self.plan.vertex_owner if cfg.balance_partitions else None
             ),
@@ -312,7 +310,6 @@ class GLISPSystem:
         log_every: int = 10,
         batch_size: int | None = None,
         prefetch: int | None = None,
-        worker_cores: tuple | None = None,
     ):
         """Build a trainer, run ``epochs``, return the (trained) trainer."""
         tr = self.trainer(
@@ -321,7 +318,6 @@ class GLISPSystem:
             opt=opt,
             batch_size=batch_size,
             prefetch=prefetch,
-            worker_cores=worker_cores,
         )
         tr.train(epochs=epochs, log_every=log_every)
         return tr

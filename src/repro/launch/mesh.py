@@ -21,12 +21,14 @@ __all__ = [
 # Published per-chip peak rates for the roofline analysis, keyed by
 # ``jax.Device.device_kind``.  Source: Google Cloud documentation, "TPU
 # v5e" (system architecture): 197 TFLOP/s bf16, 819 GB/s of HBM
-# bandwidth, 1,600 Gbit/s of interchip interconnect per chip.
+# bandwidth, 1,600 Gbit/s of interchip interconnect per chip.  ``ici_bw``
+# is per link, as the roofline charges a collective to one link: the
+# chip's 1,600 Gbit/s over its 4 links of the 2-D torus.
 PEAKS = {
     "TPU v5 lite": {
         "peak_flops_bf16": 197e12,
         "hbm_bw": 819e9,  # bytes/s
-        "ici_bw": 1600e9 / 8,  # bytes/s, all links together
+        "ici_bw": 1600e9 / 8 / 4,  # bytes/s per link
     },
 }
 

@@ -60,7 +60,6 @@ class GNNTrainer:
         prefetch: int = 0,
         inflight: int = 1,  # in-flight sample requests on the service
         spec=None,  # SamplingSpec; overrides fanouts/weighted/direction
-        worker_cores: tuple | None = None,
         partition_of: np.ndarray | None = None,
         balance_partitions: bool = False,
         feature_source=None,  # FeatureSource; None = g.vertex_feats
@@ -89,7 +88,6 @@ class GNNTrainer:
             direction=direction,
             prefetch=prefetch,
             inflight=inflight,
-            worker_cores=worker_cores,
             seed=seed,
             partition_of=partition_of,
             balance_partitions=balance_partitions,
