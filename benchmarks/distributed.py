@@ -123,7 +123,6 @@ def bench_workers(g, requests) -> None:
             _emit(f"{tag}/dispatch_p95_ms", float(np.percentile(lat, 95)))
             _emit(f"{tag}/modeled_parallel_work", stats.modeled_parallel_work)
             _emit(f"{tag}/modeled_total_work", stats.modeled_total_work)
-            _emit(f"{tag}/measured_round_s", stats.measured_round_seconds)
             _flag(
                 f"{tag}/bit_identical_vs_inproc",
                 all(_same_sub(a, b) for a, b in zip(baseline, subs)),

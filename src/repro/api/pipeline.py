@@ -33,6 +33,13 @@ per request, so the batch stream is bit-identical to ``prefetch=0`` AND to
 any ``inflight`` depth (tested in tests/test_api.py and tests/test_service.py).
 Note that in process mode the sampling-server stats live in the worker, so
 read workload counters with ``prefetch=0`` pipelines.
+
+Tracing: while JAX's profiler traces, each batch's trip is recorded in
+``repro.obs`` under its request index: ``sample.submit``, ``sample.wait``,
+``batch.build``, ``batch.bytes`` and ``batch.put`` in the producer (the
+forked worker records on the flag of its ``produce`` command and ships its
+records in the batches' messages), ``pipeline.recv`` and ``pipeline.h2d``
+in the consumer.  ``prefetch=0`` has no queue, so no put or recv.
 """
 from __future__ import annotations
 
@@ -49,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.sampling.service import DEFAULT_DIRECTION, SamplingSpec
 from repro.core.storage import as_feature_source
 from repro.data.graph_loader import SeedBatchLoader
@@ -62,6 +70,14 @@ _log = logging.getLogger(__name__)
 _FORK_AVAILABLE = os.name == "posix" and "fork" in mp.get_all_start_methods()
 
 _KEY_MASK = (1 << 64) - 1
+
+
+def _stage(batch: GNNBatch, i: int) -> GNNBatch:
+    """The batch's arrays as jax arrays (span ``pipeline.h2d``).  The copy
+    to the device is asynchronous, so the span is the host-side staging
+    only, not the transfer."""
+    with obs.span("pipeline.h2d", i):
+        return jax.tree.map(jnp.asarray, batch)
 
 
 class BatchPipeline:
@@ -173,7 +189,7 @@ class BatchPipeline:
         # the stream is independent of the service's other consumers
         self._key_base = int(seed) & _KEY_MASK
         self._req_counter = 0
-        self._pending = collections.deque()  # (seeds, SampleTicket) in order
+        self._pending = collections.deque()  # (seeds, SampleTicket, index) in order
         self._proc = None
         self._cmd_q = None
         self._data_q = None
@@ -186,37 +202,47 @@ class BatchPipeline:
         self._req_counter += 1
         return key
 
-    def _submit_ahead(self, seeds: np.ndarray) -> None:
-        ticket = self._submit(seeds, self.spec, key=self._next_key())
-        self._pending.append((seeds, ticket))
+    def _submit_ahead(self, seeds: np.ndarray) -> int:
+        key = self._next_key()
+        with obs.span("sample.submit", key[1]):
+            ticket = self._submit(seeds, self.spec, key=key)
+        self._pending.append((seeds, ticket, key[1]))
+        return key[1]
 
     def _take_sample(self, seeds: np.ndarray):
-        """The subgraph for one seed batch: the pre-submitted in-flight
-        ticket when the look-ahead window holds one, else a fresh request.
-        Keys are assigned in batch order either way, so windowed and
-        unwindowed streams are bit-identical."""
+        """``(request index, subgraph)`` for one seed batch: the
+        pre-submitted in-flight ticket when the look-ahead window holds one,
+        else a fresh request.  Keys are assigned in batch order either way,
+        so windowed and unwindowed streams are bit-identical."""
         if self._pending and np.array_equal(self._pending[0][0], seeds):
-            _, ticket = self._pending.popleft()
-            return ticket.result(timeout=self.ticket_timeout)
-        if self._submit is not None:
-            ticket = self._submit(seeds, self.spec, key=self._next_key())
-            return ticket.result(timeout=self.ticket_timeout)
-        return self._sample(
-            seeds, self.fanouts, weighted=self.weighted, direction=self.direction
-        )
+            _, ticket, i = self._pending.popleft()
+        elif self._submit is not None:
+            key = self._next_key()
+            i = key[1]
+            with obs.span("sample.submit", i):
+                ticket = self._submit(seeds, self.spec, key=key)
+        else:  # a raw client: keys only number its batches
+            i = self._next_key()[1]
+            with obs.span("sample.wait", i):
+                return i, self._sample(
+                    seeds, self.fanouts, weighted=self.weighted, direction=self.direction
+                )
+        with obs.span("sample.wait", i):
+            return i, ticket.result(timeout=self.ticket_timeout)
 
     def make_batch(self, seeds: np.ndarray) -> GNNBatch:
         """One seed batch through sampling + padding (numpy, no prefetch)."""
-        sub = self._take_sample(seeds)
-        return subgraph_to_batch(
-            sub,
-            self.feature_source,
-            self.graph.labels,
-            self.num_layers,
-            edge_types=self.graph.edge_types,
-            vertex_quantum=self.vertex_quantum,
-            edge_quantum=self.edge_quantum,
-        )
+        i, sub = self._take_sample(seeds)
+        with obs.span("batch.build", i):
+            return subgraph_to_batch(
+                sub,
+                self.feature_source,
+                self.graph.labels,
+                self.num_layers,
+                edge_types=self.graph.edge_types,
+                vertex_quantum=self.vertex_quantum,
+                edge_quantum=self.edge_quantum,
+            )
 
     def _seed_stream(self, epochs: int):
         for _ in range(epochs):
@@ -229,8 +255,7 @@ class BatchPipeline:
         """Cancel in-flight window tickets so abandoned requests stop
         consuming scheduler rounds and skewing workload counters."""
         while self._pending:
-            _, ticket = self._pending.popleft()
-            ticket.cancel()
+            self._pending.popleft()[1].cancel()
 
     def _forward_run(self, epochs: int) -> None:
         """Replay one completed run WITHOUT sampling: consume the seed
@@ -239,8 +264,7 @@ class BatchPipeline:
         where a real run would have left it.  Used by a respawned worker
         to fast-forward to the crashed run."""
         for _ in self._seed_stream(epochs):
-            if self._submit is not None:
-                self._next_key()
+            self._next_key()
 
     def _produce_np(self, epochs: int, skip: int = 0):
         """The serial producer: pure numpy, safe inside the forked worker.
@@ -262,8 +286,7 @@ class BatchPipeline:
         for _ in range(skip):
             if next(stream, None) is None:
                 break
-            if self._submit is not None:
-                self._next_key()
+            self._next_key()
         windowed = self.inflight > 1 and self._submit is not None
         # bounded by construction: the refill loop below never grows it past
         # self.inflight (validated positive), so no maxlen is needed
@@ -276,40 +299,70 @@ class BatchPipeline:
                         if nxt is None:
                             break
                         t0 = time.perf_counter()
-                        self._submit_ahead(nxt)
+                        i = self._submit_ahead(nxt)
                         self.sample_time += time.perf_counter() - t0
-                        queue.append(nxt)
+                        queue.append((i, nxt))
                     if not queue:
                         return
-                    seeds = queue.popleft()
+                    i, seeds = queue.popleft()
                 else:
                     seeds = next(stream, None)
                     if seeds is None:
                         return
+                    i = self._req_counter  # the key make_batch takes next
                 t0 = time.perf_counter()
                 batch = self.make_batch(seeds)
                 self.sample_time += time.perf_counter() - t0
-                yield seeds, batch
+                if obs.tracing():
+                    obs.count("batch.bytes", sum(x.nbytes for x in jax.tree.leaves(batch)), i)
+                yield i, seeds, batch
         finally:
             self._drop_pending()
 
     def _produce(self, epochs: int):
-        for seeds, batch in self._produce_np(epochs):
+        for i, seeds, batch in self._produce_np(epochs):
             # host->device staging rides with the producer so the consumer's
             # step loop is nothing but dispatch + block
-            yield seeds, jax.tree.map(jnp.asarray, batch)
+            yield i, seeds, _stage(batch, i)
 
-    def batches(self, epochs: int = 1):
-        """Yield ``(seeds, GNNBatch)`` with arrays staged as jax arrays;
-        sampling runs ahead of the consumer when ``prefetch >= 1``."""
+    def _thread_batches(self, epochs: int):
+        def produce():
+            for i, seeds, batch in self._produce(epochs):
+                # prefetch_iterator's thread resumes this generator once
+                # its bounded put has returned
+                with obs.span("batch.put", i):
+                    yield i, seeds, batch
+
+        # prefetch_iterator stops and joins its producer when the generator
+        # is closed/abandoned, so the shared loader/backend state is never
+        # mutated concurrently with a later epoch
+        items = prefetch_iterator(produce(), self.prefetch)
+        try:
+            while True:
+                with obs.span("pipeline.recv") as recv:
+                    item = next(items, None)
+                    if item is not None:
+                        recv.batch = item[0]
+                if item is None:
+                    return
+                yield item
+        finally:
+            items.close()
+
+    def indexed(self, epochs: int = 1):
+        """Yield ``(request index, seeds, GNNBatch)`` with arrays staged as
+        jax arrays; sampling runs ahead of the consumer when
+        ``prefetch >= 1``.  The index is the ``i`` of the batch's sample key
+        ``(seed, i)``, which the spans of its trip carry (``repro.obs``)."""
         if self.prefetch <= 0:
             return self._produce(epochs)
         if self.workers == "process" and _FORK_AVAILABLE:
             return self._process_batches(epochs)
-        # thread mode: prefetch_iterator stops and joins its producer when
-        # the generator is closed/abandoned, so the shared loader/backend
-        # state is never mutated concurrently with a later epoch
-        return prefetch_iterator(self._produce(epochs), self.prefetch)
+        return self._thread_batches(epochs)
+
+    def batches(self, epochs: int = 1):
+        """Yield ``(seeds, GNNBatch)``: :meth:`indexed` without the index."""
+        return ((seeds, batch) for _, seeds, batch in self.indexed(epochs))
 
     def __iter__(self):
         return self.batches(1)
@@ -328,10 +381,15 @@ class BatchPipeline:
                 self._forward_run(cmd[1])
                 self._data_q.put(("fwd",))
                 continue
+            _, epochs, skip, tracing = cmd
+            obs.forked(tracing)
             try:
-                for seeds, batch in self._produce_np(cmd[1], skip=cmd[2]):
-                    self._data_q.put(("item", seeds, batch))
-                self._data_q.put(("done", self.sample_time))
+                for i, seeds, batch in self._produce_np(epochs, skip=skip):
+                    # the message carries what was recorded before this put;
+                    # the put's own span rides with the next one
+                    with obs.span("batch.put", i):
+                        self._data_q.put(("item", i, seeds, batch, obs.take()))
+                self._data_q.put(("done", self.sample_time, obs.take()))
             except BaseException as exc:  # noqa: BLE001 - re-raised in parent
                 self._data_q.put(
                     ("error", f"{type(exc).__name__}: {exc}\n{traceback.format_exc()}")
@@ -400,7 +458,7 @@ class BatchPipeline:
                 raise RuntimeError(
                     "respawned prefetch worker failed to replay run history"
                 )
-        self._cmd_q.put(("produce", epochs, delivered))
+        self._cmd_q.put(("produce", epochs, delivered, obs.tracing()))
 
     def _read_or_respawn(self, epochs: int, delivered: int):
         """Queue read; a dead worker is respawned (crash budget permitting)
@@ -430,24 +488,30 @@ class BatchPipeline:
     def _process_batches(self, epochs: int):
         self._ensure_worker()
         self._cancel.clear()
-        self._cmd_q.put(("produce", epochs, 0))
+        # the child records while this process traces, and ships its records
+        self._cmd_q.put(("produce", epochs, 0, obs.tracing()))
         delivered = 0
         finished = False
         try:
             while True:
-                msg = self._read_or_respawn(epochs, delivered)
+                with obs.span("pipeline.recv") as recv:
+                    msg = self._read_or_respawn(epochs, delivered)
+                    if msg[0] == "item":
+                        recv.batch = msg[1]
                 if msg[0] == "done":
                     finished = True
                     self.sample_time = msg[1]  # worker's cumulative clock
+                    obs.extend(msg[2])
                     self._run_history.append(epochs)
                     return
                 if msg[0] == "error":
                     finished = True
                     self.close()
                     raise RuntimeError(f"prefetch worker failed:\n{msg[1]}")
-                _, seeds, batch = msg
+                _, i, seeds, batch, records = msg
+                obs.extend(records)
                 delivered += 1
-                yield seeds, jax.tree.map(jnp.asarray, batch)
+                yield i, seeds, _stage(batch, i)
         finally:
             if not finished and self._proc is not None:
                 # consumer stopped early (e.g. max_steps): cancel the run
@@ -461,6 +525,8 @@ class BatchPipeline:
                         # worker died mid-drain: the run was already being
                         # abandoned, nothing left to recover
                         break
+                    if msg[0] in ("item", "done"):
+                        obs.extend(msg[-1])
                     if msg[0] == "done":
                         self.sample_time = msg[1]
                         # an abandoned run still advanced the worker's

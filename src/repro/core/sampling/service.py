@@ -50,6 +50,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro import obs
 from repro.core.faults import CircuitBreaker, InjectedFault, RetryPolicy, as_injector
 from repro.graph.graph import GraphPartition, HeteroGraph
 
@@ -197,16 +198,15 @@ class ServerStats:
 class ServiceStats(ServerStats):
     """``SamplingService.stats()``: the merged per-server counters plus the
     service-level work accounting, with the *modeled* numbers explicitly
-    named as such so benchmarks can no longer conflate them with the
-    *measured* wall-clock per-round time reported alongside."""
+    named as such.  The rounds' times are spans (``sample.round``,
+    ``repro.obs``)."""
 
     # the Fig.-10 work model (edges touched + per-seed overhead), NOT a
     # measurement: per-round MAX across servers / per-round SUM
     modeled_parallel_work: float = 0.0
     modeled_total_work: float = 0.0
-    # measured: scheduling rounds driven and their wall-clock total
+    # scheduling rounds driven
     rounds: int = 0
-    measured_round_seconds: float = 0.0
 
     @property
     def parallel_work(self) -> float:
@@ -898,7 +898,6 @@ class SamplingService:
         self.modeled_parallel_work = 0.0
         self.modeled_total_work = 0.0
         self.rounds = 0
-        self.measured_round_seconds = 0.0
         self._inflight: list[_RequestState] = []
         self._auto_key = 0
         # rounds are serialized: concurrent consumers (e.g. a thread-mode
@@ -1001,7 +1000,7 @@ class SamplingService:
         """Service-level aggregate: per-server counters (primaries and
         replicas, remote workers' included) merged into one, the
         service's lost-dispatch count in ``degraded``, the explicitly
-        modeled work totals, and the measured per-round wall clock."""
+        modeled work totals, and the number of rounds."""
         merged = ServiceStats()
         if self.dispatcher is not None:
             for d in self.dispatcher.server_stats().values():
@@ -1012,7 +1011,6 @@ class SamplingService:
         merged.modeled_parallel_work = self.modeled_parallel_work
         merged.modeled_total_work = self.modeled_total_work
         merged.rounds = self.rounds
-        merged.measured_round_seconds = self.measured_round_seconds
         return merged
 
     def server_health(self) -> dict[str, str]:
@@ -1042,7 +1040,6 @@ class SamplingService:
         self.modeled_parallel_work = 0.0
         self.modeled_total_work = 0.0
         self.rounds = 0
-        self.measured_round_seconds = 0.0
 
     def close(self, timeout: float = 2.0) -> None:
         """Shut down the remote worker pool, if any (in-process services
@@ -1088,38 +1085,37 @@ class SamplingService:
             active = list(self._inflight)
             if not active:
                 return
-            t0 = time.perf_counter()
-            # remote mode: work is booked in the worker processes; the
-            # snapshots riding on collected results give per-partition
-            # (= per worker host) sums with no extra round-trip.  The
-            # parallel-work MAX is then over hosts rather than over
-            # individual replica servers — the right granularity, since a
-            # partition's replicas share one host either way.
-            if self.dispatcher is not None:
-                w0 = self.dispatcher.snapshot_workloads()
-            else:
-                w0 = [srv.stats.work_units for srv in self._all_servers]
-            # dispatch log keyed by the SERVING server (primary or a
-            # failover replica), so coalescing rebates hit the stats that
-            # were actually charged
-            log: dict[int, tuple[SamplingServer, list]] = {}
+            with obs.span("sample.round", inflight=len(active)):
+                # remote mode: work is booked in the worker processes; the
+                # snapshots riding on collected results give per-partition
+                # (= per worker host) sums with no extra round-trip.  The
+                # parallel-work MAX is then over hosts rather than over
+                # individual replica servers — the right granularity, since a
+                # partition's replicas share one host either way.
+                if self.dispatcher is not None:
+                    w0 = self.dispatcher.snapshot_workloads()
+                else:
+                    w0 = [srv.stats.work_units for srv in self._all_servers]
+                # dispatch log keyed by the SERVING server (primary or a
+                # failover replica), so coalescing rebates hit the stats that
+                # were actually charged
+                log: dict[int, tuple[SamplingServer, list]] = {}
 
-            def on_dispatch(p, chunk, srv):
-                log.setdefault(id(srv), (srv, []))[1].append(chunk)
+                def on_dispatch(p, chunk, srv):
+                    log.setdefault(id(srv), (srv, []))[1].append(chunk)
 
-            for st in active:
-                self._execute_hop(st, on_dispatch)
-            if self.coalesce:
-                self._coalesce_credit(log)
-            if self.dispatcher is not None:
-                w1 = self.dispatcher.snapshot_workloads()
-            else:
-                w1 = [srv.stats.work_units for srv in self._all_servers]
-            deltas = [b - a for a, b in zip(w0, w1)]
-            self.modeled_parallel_work += max(deltas) if deltas else 0.0
-            self.modeled_total_work += sum(deltas)
-            self.rounds += 1
-            self.measured_round_seconds += time.perf_counter() - t0
+                for st in active:
+                    self._execute_hop(st, on_dispatch)
+                if self.coalesce:
+                    self._coalesce_credit(log)
+                if self.dispatcher is not None:
+                    w1 = self.dispatcher.snapshot_workloads()
+                else:
+                    w1 = [srv.stats.work_units for srv in self._all_servers]
+                deltas = [b - a for a, b in zip(w0, w1)]
+                self.modeled_parallel_work += max(deltas) if deltas else 0.0
+                self.modeled_total_work += sum(deltas)
+                self.rounds += 1
             self._inflight = [st for st in self._inflight if not st.done]
         finally:
             self._lock.release()

@@ -58,7 +58,6 @@ class DPTrainLog:
     losses: list = field(default_factory=list)
     # single-device reference losses (reference=True), same positions
     ref_losses: list = field(default_factory=list)
-    wall: list = field(default_factory=list)
     sample_time: float = 0.0
     compute_time: float = 0.0
 

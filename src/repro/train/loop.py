@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api.pipeline import BatchPipeline
 from repro.core.sampling.service import DEFAULT_DIRECTION
 from repro.data.graph_loader import SeedBatchLoader
@@ -38,8 +39,6 @@ __all__ = ["GNNTrainer", "LMTrainer"]
 class TrainLog:
     steps: list = field(default_factory=list)
     losses: list = field(default_factory=list)
-    accs: list = field(default_factory=list)
-    wall: list = field(default_factory=list)
     sample_time: float = 0.0
     compute_time: float = 0.0
 
@@ -160,7 +159,7 @@ class GNNTrainer:
     ):
         step = 0
         skip = self._resume_step  # batches already trained before resume()
-        for seeds, batch in self.pipeline.batches(epochs):
+        for i, _, batch in self.pipeline.indexed(epochs):
             if max_steps is not None and step >= max_steps:
                 break
             if step < skip:
@@ -169,10 +168,12 @@ class GNNTrainer:
                 step += 1
                 continue
             t1 = time.perf_counter()
-            self.params, self.opt_state, loss = self._step(
-                self.params, self.opt_state, batch
-            )
-            loss = float(loss)
+            # i is the batch's request index, which its producer spans carry
+            with obs.span("train.step", i), obs.step("train", i):
+                self.params, self.opt_state, loss = self._step(
+                    self.params, self.opt_state, batch
+                )
+                loss = float(loss)
             t2 = time.perf_counter()
             self.log.compute_time += t2 - t1
             if step % log_every == 0:

@@ -377,7 +377,6 @@ def test_service_stats_modeled_and_measured(small_graph):
     assert s.modeled_parallel_work > 0
     assert s.modeled_total_work >= s.modeled_parallel_work
     assert s.rounds > 0
-    assert s.measured_round_seconds > 0
     # deprecated read aliases stay observable for one release
     assert s.parallel_work == s.modeled_parallel_work
     assert s.total_work == s.modeled_total_work
@@ -387,7 +386,7 @@ def test_service_stats_modeled_and_measured(small_graph):
     assert svc.modeled_parallel_work == 0.0
     system.reset_stats()
     s2 = system.backend.stats()
-    assert (s2.rounds, s2.measured_round_seconds) == (0, 0.0)
+    assert s2.rounds == 0
 
 
 # ---------------------------------------------------------------------------
