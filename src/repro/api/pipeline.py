@@ -24,6 +24,18 @@ Two worker modes:
     sections (numpy only releases the GIL for a handful of ops), so a
     separate process is the only way host sampling truly runs beside XLA
     compute — the same reason DGL/PyTorch dataloaders use worker processes.
+    The batch's arrays do not cross the queue's pipe: the worker copies
+    them into one of ``prefetch + 2`` shared-memory slots (anonymous
+    ``memfd`` files made before the fork; what the queue holds, the one the
+    consumer holds, the one being filled) and queues only a small message,
+    from which the consumer rebuilds the arrays as views of the slot.  A
+    slot grows to the largest batch it has carried, so the slots cost
+    ``prefetch + 2`` batches of host memory.  The consumer returns a slot
+    when it asks for the next batch, once the copy to the device has read
+    it; on the CPU backend, where ``jnp.asarray`` may alias host memory,
+    the staged arrays are copied out of the slot first.  Where no slot can
+    be had (no ``memfd``, or a slot cannot grow) the batch goes pickled
+    through the queue instead.
 ``thread`` — in-process double buffering via a daemon thread.  Zero-copy
     hand-off, but overlap is limited to the consumer's GIL-released windows.
 
@@ -38,19 +50,24 @@ Tracing: while JAX's profiler traces, each batch's trip is recorded in
 ``repro.obs`` under its request index: ``sample.submit``, ``sample.wait``,
 ``batch.build``, ``batch.bytes`` and ``batch.put`` in the producer (the
 forked worker records on the flag of its ``produce`` command and ships its
-records in the batches' messages), ``pipeline.recv`` and ``pipeline.h2d``
-in the consumer.  ``prefetch=0`` has no queue, so no put or recv.
+records in the batches' messages; its ``batch.put`` is the wait for a free
+slot, the copy into it and the queue put, and its ``batch.shm_bytes`` the
+bytes that went through a slot, 0 for a pickled batch), ``pipeline.recv``
+and ``pipeline.h2d`` in the consumer.  ``prefetch=0`` has no queue, so no
+put or recv.
 """
 from __future__ import annotations
 
 import collections
 import logging
+import mmap
 import multiprocessing as mp
 import os
 import queue as queue_mod
 import time
 import traceback
 import warnings
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +95,130 @@ def _stage(batch: GNNBatch, i: int) -> GNNBatch:
     only, not the transfer."""
     with obs.span("pipeline.h2d", i):
         return jax.tree.map(jnp.asarray, batch)
+
+
+_ALIGN = 64  # byte alignment of each array in a slot
+
+
+class _Slot(NamedTuple):
+    """What the worker sends for a batch placed in a slot: enough to
+    rebuild its arrays as views of the slot's memory."""
+
+    slot: int
+    size: int  # the slot's size when it was filled
+    tree: object  # the batch's PyTreeDef (the layer lists)
+    leaves: list  # (offset, shape, dtype) per array leaf
+
+
+class _SlotRing:
+    """Shared-memory slots that carry the forked worker's batches to the
+    trainer, so that only a small message crosses the queue's pipe.
+
+    Each slot is an anonymous memory file (``memfd``) made before the fork:
+    the worker inherits its descriptor, and no name exists that could be
+    left behind or that ``resource_tracker`` would register.  The memory is
+    freed once both processes have closed the descriptor and unmapped it.
+    The worker regrows a slot (``ftruncate``) when a batch is larger than
+    it, so slot sizes follow the observed batch size.  Free slot ids flow
+    back to the worker through a pipe; the worker starts holding them all.
+    Where no ``memfd`` exists, or a slot cannot grow, batches go through
+    the pickled queue pipe instead.
+    """
+
+    def __init__(self, n: int, ctx):
+        try:
+            self.fds = [os.memfd_create("glisp-batch") for _ in range(n)]
+        except (AttributeError, OSError) as exc:  # no memfd on this platform
+            _log.debug("no shared-memory batch slots: %s", exc)
+            self.fds = None
+        self.maps: list = [None] * n  # this process's mapping of each slot
+        self._free_r, self._free_w = ctx.Pipe(duplex=False)
+        self._idle = collections.deque(range(n))  # worker side: free slot ids
+
+    def _map(self, slot: int, size: int) -> mmap.mmap:
+        mm = self.maps[slot]
+        if mm is None or len(mm) < size:
+            # a mapping a consumer's views still use is unmapped by their GC
+            self.maps[slot] = mm = mmap.mmap(self.fds[slot], size)
+        return mm
+
+    def _acquire(self, cancel) -> int | None:
+        if self._idle:
+            return self._idle.popleft()
+        while not self._free_r.poll(0.05):
+            if cancel.is_set():
+                return None
+        return self._free_r.recv()
+
+    def pack(self, batch: GNNBatch, cancel) -> tuple:
+        """Worker side: ``(payload, bytes placed)``.  The payload is a
+        :class:`_Slot` once the batch's arrays are copied into a free slot,
+        the batch itself where it cannot be placed, or None where the run
+        was cancelled while waiting for a free slot."""
+        leaves, tree = jax.tree.flatten(batch)
+        if self.fds is None or not all(isinstance(x, np.ndarray) for x in leaves):
+            return batch, 0
+        metas, end = [], 0
+        for x in leaves:
+            off = -(-end // _ALIGN) * _ALIGN
+            metas.append((off, x.shape, x.dtype))
+            end = off + x.nbytes
+        slot = self._acquire(cancel)
+        if slot is None:
+            return None, 0
+        size = -(-max(end, 1) // mmap.PAGESIZE) * mmap.PAGESIZE
+        try:
+            if self.maps[slot] is None or len(self.maps[slot]) < size:
+                os.ftruncate(self.fds[slot], size)
+            mm = self._map(slot, size)
+        except OSError as exc:  # the slot cannot grow: send it pickled
+            _log.warning("batch of %d bytes goes through the pickled queue: %s", end, exc)
+            self._idle.append(slot)
+            return batch, 0
+        for x, (off, shape, dtype) in zip(leaves, metas):
+            np.ndarray(shape, dtype, mm, off)[...] = x
+        return _Slot(slot, len(mm), tree, metas), sum(x.nbytes for x in leaves)
+
+    def unpack(self, msg: _Slot) -> GNNBatch:
+        """Trainer side: the batch's arrays as numpy views of its slot."""
+        mm = self._map(msg.slot, msg.size)
+        leaves = [np.ndarray(shape, dtype, mm, off) for off, shape, dtype in msg.leaves]
+        return jax.tree.unflatten(msg.tree, leaves)
+
+    def release(self, slot: int) -> None:
+        """Trainer side: the slot may be filled again."""
+        if self.fds is not None:
+            self._free_w.send(slot)
+
+    def close(self) -> None:
+        fds, self.fds = self.fds, None
+        self._free_r.close()
+        self._free_w.close()
+        for mm in self.maps:
+            if mm is not None:
+                try:
+                    mm.close()
+                except BufferError:  # views still alive: unmapped by their GC
+                    pass
+        for fd in fds or ():
+            os.close(fd)
+
+
+def _release(held) -> None:
+    """Return a yielded batch's slot to the worker once the batch's copy
+    from the slot has finished (in the trainer, ``float(loss)`` has already
+    waited for the step that read it)."""
+    if held is not None:
+        ring, slot, staged = held
+        jax.block_until_ready(staged)
+        ring.release(slot)
+
+
+def _host_aliased(staged: GNNBatch) -> bool:
+    """Whether ``jnp.asarray`` may have kept a view of the host arrays: on
+    the CPU backend it aliases suitably aligned numpy memory."""
+    leaf = jax.tree.leaves(staged)[0]
+    return any(d.platform == "cpu" for d in leaf.devices())
 
 
 class BatchPipeline:
@@ -194,6 +335,7 @@ class BatchPipeline:
         self._cmd_q = None
         self._data_q = None
         self._cancel = None  # mp.Event: stop the worker's current run early
+        self._ring = None  # _SlotRing: the worker's batches, in shared memory
         self._run_history: list[int] = []  # epochs of fully produced runs
 
     # ------------------------------------------------------------------
@@ -388,7 +530,11 @@ class BatchPipeline:
                     # the message carries what was recorded before this put;
                     # the put's own span rides with the next one
                     with obs.span("batch.put", i):
-                        self._data_q.put(("item", i, seeds, batch, obs.take()))
+                        payload, placed = self._ring.pack(batch, self._cancel)
+                        if payload is None:  # cancelled waiting for a slot
+                            break
+                        obs.count("batch.shm_bytes", placed, i)
+                        self._data_q.put(("item", i, seeds, payload, obs.take()))
                 self._data_q.put(("done", self.sample_time, obs.take()))
             except BaseException as exc:  # noqa: BLE001 - re-raised in parent
                 self._data_q.put(
@@ -402,6 +548,10 @@ class BatchPipeline:
         self._cmd_q = ctx.SimpleQueue()
         self._data_q = ctx.Queue(maxsize=max(1, self.prefetch))
         self._cancel = ctx.Event()
+        if self._ring is not None:  # a dead worker's slots
+            self._ring.close()
+        # what the queue holds, one slot the consumer holds, one being filled
+        self._ring = _SlotRing(max(1, self.prefetch) + 2, ctx)
         with warnings.catch_warnings():
             # jax warns that fork + threads can deadlock; the child touches
             # only numpy state, never XLA, which is the supported pattern
@@ -492,12 +642,15 @@ class BatchPipeline:
         self._cmd_q.put(("produce", epochs, 0, obs.tracing()))
         delivered = 0
         finished = False
+        held = None  # (ring, slot, staged batch) of the item last yielded
         try:
             while True:
                 with obs.span("pipeline.recv") as recv:
                     msg = self._read_or_respawn(epochs, delivered)
                     if msg[0] == "item":
                         recv.batch = msg[1]
+                        ring, payload = self._ring, msg[3]
+                        batch = ring.unpack(payload) if isinstance(payload, _Slot) else payload
                 if msg[0] == "done":
                     finished = True
                     self.sample_time = msg[1]  # worker's cumulative clock
@@ -508,11 +661,20 @@ class BatchPipeline:
                     finished = True
                     self.close()
                     raise RuntimeError(f"prefetch worker failed:\n{msg[1]}")
-                _, i, seeds, batch, records = msg
+                _, i, seeds, _, records = msg
                 obs.extend(records)
                 delivered += 1
-                yield i, seeds, _stage(batch, i)
+                staged = _stage(batch, i)
+                if isinstance(payload, _Slot):
+                    if _host_aliased(staged):
+                        staged = jax.tree.map(jnp.array, staged)  # own the memory
+                    held = (ring, payload.slot, staged)
+                del batch  # views of the slot
+                yield i, seeds, staged
+                _release(held)  # the consumer asks for the next item
+                held = None
         finally:
+            _release(held)
             if not finished and self._proc is not None:
                 # consumer stopped early (e.g. max_steps): cancel the run
                 # and drain the few in-flight items so the worker is idle
@@ -527,6 +689,8 @@ class BatchPipeline:
                         break
                     if msg[0] in ("item", "done"):
                         obs.extend(msg[-1])
+                    if msg[0] == "item" and isinstance(msg[3], _Slot):
+                        self._ring.release(msg[3].slot)
                     if msg[0] == "done":
                         self.sample_time = msg[1]
                         # an abandoned run still advanced the worker's
@@ -562,6 +726,9 @@ class BatchPipeline:
             if proc.is_alive():
                 proc.kill()
                 proc.join(timeout=timeout)
+        ring, self._ring = self._ring, None
+        if ring is not None:
+            ring.close()
 
     def __del__(self):  # best effort; daemon children die with the parent
         try:
