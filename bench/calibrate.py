@@ -5,9 +5,11 @@
 For each seed, in one process over one built system: the program's
 compared numbers against the reference (the sound runs: their largest is
 the lower reading of each limit), and for the control seeds the same
-numbers of the control (the reference in bfloat16) and of a planted fault
-(the reference with half the batch left out of the loss), the upper
-readings, beside the program's.  One JSON line per seed and kind.  Not part of a benchmark run.
+numbers of the control (the reference in bfloat16) and of the planted
+faults the cell's kind names (``Cell.faults``: the reference with half the
+batch left out of the loss; in a data-parallel cell also with one chip's
+shard left out), the upper readings, beside the program's.  One JSON line
+per seed and kind.  Not part of a benchmark run.
 """
 from __future__ import annotations
 
@@ -29,8 +31,6 @@ def main(argv=None) -> int:
     ap.add_argument("--control-seeds", default="")
     args = ap.parse_args(argv)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    import jax.numpy as jnp
-
     from reference import train as ref_train
 
     spec = common.Spec(args.workload)
@@ -48,11 +48,7 @@ def main(argv=None) -> int:
             ref = cell.reference(blocks)
             rows = {"program": cell.prog}
             if seed in controls:
-                rows = {
-                    "program": cell.prog,
-                    "control": cell.reference(blocks, dtype=jnp.bfloat16),
-                    "half_batch": cell.reference(blocks, half_batch=True),
-                }
+                rows.update(cell.faults(blocks))
             for kind, got in rows.items():
                 gaps = ref_train.compare(got, ref)
                 print(json.dumps({"seed": seed, "kind": kind, **gaps, "losses": got["losses"], "ref_losses": ref["losses"]}), flush=True)

@@ -88,20 +88,33 @@ _GRAPH_FIELDS = (
 )
 
 
+def graph_key(params: dict) -> str:
+    """The cache key of a configuration's graph: a hash of its parameters."""
+    return hashlib.sha256(json.dumps(params, sort_keys=True).encode()).hexdigest()[:16]
+
+
+def graph_generator(name: str):
+    """The program's generator ``<name>_graph`` in ``repro.graph.generate``,
+    so that a configuration can name a generator added there later."""
+    from repro.graph import generate
+
+    gen = getattr(generate, f"{name}_graph", None)
+    if not callable(gen):
+        raise BenchError(f"no graph generator {name!r}: repro.graph.generate has no {name}_graph")
+    return gen
+
+
 def load_graph(params: dict):
     """The configuration's graph, generated once from its own fixed seed
     and kept under ``.cache/graphs``; later runs read it back."""
     from repro.graph import HeteroGraph
-    from repro.graph.generate import erdos_renyi_graph, power_law_graph
 
-    key = hashlib.sha256(json.dumps(params, sort_keys=True).encode()).hexdigest()[:16]
-    d = CACHE / "graphs" / key
+    d = CACHE / "graphs" / graph_key(params)
     if (d / "done").is_file():
         arrays = {f: np.load(d / f"{f}.npy") for f in _GRAPH_FIELDS}
         return HeteroGraph(num_vertices=int(params["num_vertices"]), **arrays)
-    gen = {"power_law": power_law_graph, "erdos_renyi": erdos_renyi_graph}
     kw = {k: v for k, v in params.items() if k != "generator"}
-    g = gen[params["generator"]](**kw)
+    g = graph_generator(params["generator"])(**kw)
     d.mkdir(parents=True, exist_ok=True)
     for f in _GRAPH_FIELDS:
         np.save(d / f"{f}.npy", getattr(g, f))

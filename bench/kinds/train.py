@@ -11,6 +11,7 @@ program's state is freed and the plain reference follows the first steps
 from __future__ import annotations
 
 import gc
+import inspect
 import math
 import multiprocessing as mp
 import threading
@@ -25,7 +26,29 @@ from reference import train as ref_train
 CHECKED_STEPS = 3  # the steps the reference follows
 
 
+def model_kwargs(cfg: dict, in_dim: int) -> dict:
+    """``GNNModel``'s arguments: every key of the configuration's ``model``
+    that its constructor names, the input width and the classes."""
+    from repro.models.gnn import GNNModel
+
+    named = inspect.signature(GNNModel).parameters
+    kw = {k: v for k, v in cfg["model"].items() if k in named}
+    return dict(kw, in_dim=in_dim, num_classes=cfg["graph"]["num_classes"])
+
+
+def train_seeds(cfg: dict, graph, rng: np.random.Generator) -> np.ndarray:
+    """The training seeds in the order of ``--seed``: a permutation of all
+    vertices, or, where the configuration lists ``seed_vertex_types``, of
+    the vertices of those types."""
+    types = cfg.get("seed_vertex_types")
+    if types is None:
+        return rng.permutation(graph.num_vertices)
+    return rng.permutation(np.flatnonzero(np.isin(graph.vertex_types, types)))
+
+
 class Cell:
+    shards = 1  # seed batches per step, one per chip of a data-parallel step
+
     def __init__(self, spec: common.Spec, seed: int, compiles: common.CompileCounter, built=None):
         self.spec = spec
         self.cfg = spec.config
@@ -34,6 +57,7 @@ class Cell:
         self.key_seed, self.rng = common.run_seed_key(seed)
         self.window_stats: dict = {}
         self.built = built  # (graph, system) shared by several seeds in one process
+        self.batch_size = self.cfg["system"]["batch_size"] * self.shards  # seeds per step
 
     # -- set-up -------------------------------------------------------------
     def setup(self) -> None:
@@ -46,44 +70,28 @@ class Cell:
         from repro.models.gnn import GNNModel
         from repro.train.optim import AdamWConfig, adamw_init
 
-        cfg, m = self.cfg, self.cfg["model"]
+        cfg = self.cfg
         t0 = common.now()
         self.graph, self.system = self.built or common.build_system(cfg)
         common.log(f"setup: graph and system {common.now() - t0:.1f}s")
         t0 = common.now()
         g = self.graph
-        self.train_ids = self.rng.permutation(g.num_vertices)
-        self.model = GNNModel(
-            m["kind"], g.vertex_feats.shape[1], hidden=m["hidden"],
-            num_layers=m["num_layers"], num_classes=cfg["graph"]["num_classes"],
-            num_heads=m["num_heads"],
-        )
-        tr = self.system.trainer(
-            self.model, self.train_ids, opt=AdamWConfig(**cfg["optimizer"]),
-            prefetch=self.traffic["prefetch"], inflight=self.traffic["inflight"],
-        )
-        # system.trainer does not pass the configuration's padding quanta on
-        tr.pipeline.vertex_quantum = cfg["system"]["vertex_quantum"]
-        tr.pipeline.edge_quantum = cfg["system"]["edge_quantum"]
+        self.train_ids = train_seeds(cfg, g, self.rng)
+        self.model = GNNModel(**model_kwargs(cfg, g.vertex_feats.shape[1]))
+        tr = self.make_trainer(AdamWConfig(**cfg["optimizer"]))
         tr.params = jax.jit(self.model.init)(jax.random.PRNGKey(self.key_seed))
         tr.opt_state = jax.jit(adamw_init)(tr.params)
         self.trainer = tr
         start = jax.device_get(tr.params)
 
-        # step 1, then the optimizer's state after it; steps 2..3 resume from
-        # a checkpoint on a fresh producer, so that their batches are the
-        # keyed stream's next ones, as the reference replays them
+        # step 1, then the optimizer's state after it; then steps 2..3 in a
+        # second call, on batches the reference can replay
         log = tr.train(epochs=1, max_steps=1, log_every=1)
         b1 = cfg["optimizer"]["b1"]
         first_grad = jax.tree.map(lambda m: np.asarray(m) / (1 - b1), jax.device_get(tr.opt_state["mu"]))
-        ckpt = common.CACHE / "checkpoints" / self.spec.workload["name"] / "step.npz"
-        ckpt.parent.mkdir(parents=True, exist_ok=True)
-        tr.save(str(ckpt), step=1)
-        tr.pipeline.close()
-        tr.resume(str(ckpt))
-        tr.params, tr.opt_state = jax.device_put((tr.params, tr.opt_state))
+        max_steps = self.between_checked_calls()
         t1 = common.now()
-        tr.train(epochs=1, max_steps=CHECKED_STEPS, log_every=1)
+        tr.train(epochs=1, max_steps=max_steps, log_every=1)
         self.checked_call_s = common.now() - t1
         self.prog = {
             "losses": list(log.losses[:CHECKED_STEPS]),
@@ -92,6 +100,37 @@ class Cell:
             "end": jax.device_get(tr.params),
         }
         common.log(f"setup: trainer and {CHECKED_STEPS} checked steps {common.now() - t0:.1f}s")
+
+    def make_trainer(self, opt):
+        tr = self.system.trainer(
+            self.model, self.train_ids, opt=opt,
+            prefetch=self.traffic["prefetch"], inflight=self.traffic["inflight"],
+        )
+        # system.trainer does not pass the configuration's padding quanta on
+        tr.pipeline.vertex_quantum = self.cfg["system"]["vertex_quantum"]
+        tr.pipeline.edge_quantum = self.cfg["system"]["edge_quantum"]
+        return tr
+
+    def pipelines(self) -> list:
+        return [self.trainer.pipeline]
+
+    def sample_time(self) -> float:
+        """The producer's clock of sampling and batch build so far."""
+        return self.trainer.pipeline.sample_time
+
+    def between_checked_calls(self) -> int:
+        """Steps 2..3 resume from a checkpoint on a fresh producer, so that
+        their batches are the keyed stream's next ones, as the reference
+        replays them.  Returns the second call's ``max_steps``, which
+        counts the resumed step."""
+        tr = self.trainer
+        ckpt = common.CACHE / "checkpoints" / self.spec.workload["name"] / "step.npz"
+        ckpt.parent.mkdir(parents=True, exist_ok=True)
+        tr.save(str(ckpt), step=1)
+        tr.pipeline.close()
+        tr.resume(str(ckpt))
+        tr.params, tr.opt_state = jax.device_put((tr.params, tr.opt_state))
+        return CHECKED_STEPS
 
     def warm_up(self) -> None:
         """Calls of w steps until one compiles nothing.  The steps of the
@@ -144,8 +183,8 @@ class Cell:
     def window(self, seconds: float) -> None:
         tr, log = self.trainer, self.trainer.log
         steps = max(CHECKED_STEPS, int(round((seconds - self.call_s) / self.step_s)) + 1)
-        per_epoch = len(self.train_ids) // self.cfg["system"]["batch_size"]
-        n_losses, compute0, sample0 = len(log.losses), log.compute_time, tr.pipeline.sample_time
+        per_epoch = len(self.train_ids) // self.batch_size
+        n_losses, compute0, sample0 = len(log.losses), log.compute_time, self.sample_time()
         t0 = common.now()
         tr.train(epochs=steps // per_epoch + 1, max_steps=steps, log_every=1)
         t1 = common.now()
@@ -155,7 +194,7 @@ class Cell:
             "steps": steps,
             "done": int(losses.size),
             "failed": int(np.sum(~np.isfinite(losses))) + steps - int(losses.size),
-            "seeds": losses.size * self.cfg["system"]["batch_size"],
+            "seeds": losses.size * self.batch_size,
             "compute_s": log.compute_time - compute0,
             "sample_s": log.sample_time - sample0,
         }
@@ -166,21 +205,25 @@ class Cell:
 
     def readings(self) -> dict:
         """What the per-layer readers of a training cell read."""
-        from flops import train_step_flops
-
         w = dict(self.window_stats)
-        # the window's batches come from a stream position that depends on
-        # timing; the keyed stream's first batches have the same sizes in
-        # law, so the work per step is their mean
-        subs = [sub for _, sub in self._replay(self.traffic["flops_batches"])]
-        w["flops_per_step"] = float(np.mean([train_step_flops(s, self.cfg) for s in subs]))
+        w["flops_per_step"] = self.flops_per_step()
         w["kind"] = "train"
         return w
+
+    def flops_per_step(self) -> float:
+        """The window's batches come from a stream position that depends
+        on timing; the keyed stream's first batches have the same sizes in
+        law, so the work per step is their mean."""
+        from flops import train_step_flops
+
+        subs = [sub for _, sub in self._replay(self.traffic["flops_batches"])]
+        return float(np.mean([train_step_flops(s, self.cfg) for s in subs]))
 
     # -- the check ----------------------------------------------------------
     def release(self) -> None:
         """Free the program's state on the device before the reference."""
-        self.trainer.pipeline.close()
+        for pl in self.pipelines():
+            pl.close()
         self.trainer.params = self.trainer.opt_state = None
         gc.collect()
 
@@ -188,13 +231,21 @@ class Cell:
         """The keyed batch stream's first ``n`` seed batches and samples: the
         seeds the trainer's loader draws first, each with its request key
         ``(seed, batch index)``."""
+        seed = self.cfg["system"].get("seed", 0)
+        return self.stream(self.train_ids, seed, n)
+
+    def stream(self, ids: np.ndarray, seed: int, n: int, epoch: int = 0, first_key: int = 0):
+        """A batch pipeline's keyed stream, replayed: seed batches 0..n-1
+        of the ``epoch``-th permutation its loader (over ``ids``, from
+        ``seed``) draws, batch j sampled with the request key
+        ``(seed, first_key + j)``."""
         from repro.data.graph_loader import SeedBatchLoader
 
-        s = self.cfg["system"]
-        seed = s.get("seed", 0)
-        loader = SeedBatchLoader(self.train_ids, s["batch_size"], seed=seed)
-        for i, seeds in zip(range(n), loader.epoch()):
-            yield seeds, self.system.sample(seeds, key=(seed, i))
+        loader = SeedBatchLoader(ids, self.cfg["system"]["batch_size"], seed=seed)
+        for _ in range(epoch):
+            next(loader.epoch())  # draws that epoch's permutation
+        for j, seeds in zip(range(n), loader.epoch()):
+            yield seeds, self.system.sample(seeds, key=(seed, first_key + j))
 
     def reference_blocks(self) -> list:
         s, m = self.cfg["system"], self.cfg["model"]
@@ -211,6 +262,16 @@ class Cell:
             self.cfg["graph"]["num_classes"], blocks, dtype=dtype, half_batch=half_batch,
         )
 
+    def faults(self, blocks: list) -> dict:
+        """What the control and each planted fault give in the program's
+        place, for the upper readings of the limits (``calibrate.py``): the
+        reference wholly in bfloat16, and with half the batch left out of
+        the loss."""
+        return {
+            "control": self.reference(blocks, dtype=jnp.bfloat16),
+            "half_batch": self.reference(blocks, half_batch=True),
+        }
+
     def check(self) -> dict:
         """Each compared number with its limit."""
         limits = self.cfg["limits"]
@@ -224,6 +285,7 @@ class Cell:
 
     def close(self) -> None:
         if hasattr(self, "trainer"):
-            self.trainer.pipeline.close()
+            for pl in self.pipelines():
+                pl.close()
         if self.built is None and hasattr(self, "system"):
             self.system.close()

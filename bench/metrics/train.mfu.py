@@ -1,6 +1,7 @@
 """The training window's share of the chip's peak: the operations its
 steps need (``flops.train_step_flops`` on unpadded batch sizes) over the
-window's time and the published bf16 peak."""
+window's time and the published bf16 peak of all the chips the step runs
+on (``peak_flops``: one chip's peak times the cell's chips)."""
 
 
 def read(r: dict):

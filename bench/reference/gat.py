@@ -33,7 +33,8 @@ def init(key, in_dim: int, model: dict, num_classes: int) -> dict:
     return {"layers": layers, "out": out * (1.0 / hidden) ** 0.5}
 
 
-def layer(p: dict, h, dst, src, n: int, dot, model: dict):
+def layer(p: dict, h, dst, src, n: int, dot, model: dict, *, vtype, etype):
+    """As ``sage.layer``; untyped, so ``vtype`` and ``etype`` are not read."""
     heads = model["num_heads"]
     z = dot(h, p["w"]).reshape(n, heads, -1)
     s_dst = (z * p["a_dst"]).sum(-1)  # [n, H]

@@ -24,9 +24,10 @@ def init(key, in_dim: int, model: dict, num_classes: int) -> dict:
     return {"layers": layers, "out": out * (1.0 / model["hidden"]) ** 0.5}
 
 
-def layer(p: dict, h, dst, src, n: int, dot, model: dict):
+def layer(p: dict, h, dst, src, n: int, dot, model: dict, *, vtype, etype):
     """``dst``/``src``: per edge, the row aggregated into and the row whose
-    message it carries."""
+    message it carries.  The model is untyped: ``vtype`` and ``etype`` are
+    not read."""
     agg = jax.ops.segment_sum(h[src], dst, num_segments=n)
     cnt = jax.ops.segment_sum(jnp.ones(dst.shape, h.dtype), dst, num_segments=n)
     mean = agg / jnp.maximum(cnt, 1.0)[:, None]

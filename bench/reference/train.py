@@ -12,6 +12,19 @@ hop starts from vertices reached by the hops before it.
 
 ``dtype=bfloat16`` turns it into the control: all of it in bfloat16,
 the weights, the optimizer's state and its update included.
+
+A model kind's reference is ``<kind>.py`` beside this file, with
+``init(key, in_dim, model, num_classes)`` and
+``layer(p, h, dst, src, n, dot, model, *, vtype, etype)``.  Per layer it
+is given, for each edge slot, the row that aggregates (``dst``) and the
+row whose message it carries (``src``), and, for a model on a typed
+graph, ``vtype``, the graph's vertex type of each row, and ``etype``, the
+graph's type of each sampled edge (0 on padding).  The direction: the
+sampler draws a frontier vertex's out-edges, so a sampled edge runs in the
+graph from the frontier vertex (``dst`` here) to its neighbour (``src``
+here), and the message flows against it, from the neighbour to the
+frontier vertex.  ``etype`` is the type of that graph edge as it is
+stored; a typed reference maps it to the relation of its message itself.
 """
 from __future__ import annotations
 
@@ -74,12 +87,16 @@ def block(sub, graph, num_layers: int, vpad: int, epad: int) -> dict:
         raise SampleError(f"{verts.size} vertices do not fit the {vpad} rows of the reference")
     feats = np.zeros((vpad, graph.vertex_feats.shape[1]), np.float32)
     feats[: verts.size] = graph.vertex_feats[verts]
+    vtype = np.zeros(vpad, np.int32)
+    vtype[: verts.size] = graph.vertex_types[verts]
     out = {
         "feats": feats,
+        "vtype": vtype,
         "seed_pos": np.searchsorted(verts, sub.seeds).astype(np.int32),
         "labels": graph.labels[sub.seeds].astype(np.int32),
         "dst": [],
         "src": [],
+        "etype": [],
     }
     for k in range(num_layers):
         hops = sub.hops[: num_layers - k]
@@ -89,10 +106,31 @@ def block(sub, graph, num_layers: int, vpad: int, epad: int) -> dict:
             raise SampleError(f"{s.size} edges do not fit the {epad} slots of the reference")
         dst = np.full(epad, vpad - 1, np.int32)
         src = np.full(epad, vpad - 1, np.int32)
+        etype = np.zeros(epad, np.int32)
         dst[: s.size] = np.searchsorted(verts, s)  # the frontier vertex aggregates
         src[: s.size] = np.searchsorted(verts, t)  # its sampled neighbour's message
+        etype[: s.size] = graph.edge_types[np.concatenate([h.eid for h in hops])]
         out["dst"].append(dst)
         out["src"].append(src)
+        out["etype"].append(etype)
+    return out
+
+
+def merge(blocks: list) -> dict:
+    """Blocks side by side as one: the rows of block j follow those of the
+    blocks before it, its edges point at its own rows, and its seeds and
+    labels follow theirs, so the mean loss over the merged seeds is the
+    mean of the blocks' mean losses where they hold as many seeds each."""
+    offsets = np.cumsum([0] + [b["feats"].shape[0] for b in blocks[:-1]]).astype(np.int32)
+
+    def side_by_side(parts, shift: bool):
+        return np.concatenate([x + o if shift else x for x, o in zip(parts, offsets)])
+
+    layers = range(len(blocks[0]["dst"]))
+    out = {key: side_by_side([b[key] for b in blocks], key == "seed_pos")
+           for key in ("feats", "vtype", "seed_pos", "labels")}
+    for key in ("dst", "src", "etype"):
+        out[key] = [side_by_side([b[key][k] for b in blocks], key != "etype") for k in layers]
     return out
 
 
@@ -123,7 +161,10 @@ def make_step(kind: str, model: dict, opt: dict, dtype, *, half_batch: bool = Fa
         h = blk["feats"].astype(dtype)
         n = h.shape[0]
         for k, pk in enumerate(params["layers"]):
-            h = mod.layer(pk, h, blk["dst"][k], blk["src"][k], n, dot, model)
+            h = mod.layer(
+                pk, h, blk["dst"][k], blk["src"][k], n, dot, model,
+                vtype=blk["vtype"], etype=blk["etype"][k],
+            )
         logits = dot(h[blk["seed_pos"]], params["out"])
         labels = blk["labels"]
         if half_batch:

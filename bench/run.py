@@ -90,7 +90,9 @@ def run_cell(
             reduced = trace_reduce.reduce(trace_reduce.find_trace(trace_dir), WINDOW_SPAN)
             shutil.rmtree(trace_dir, ignore_errors=True)
             readings = cell.readings()
-            readings.update(window_compiles=window_compiles, peak_flops=peak, trace=reduced)
+            # the peak of all the chips the cell's step runs on
+            peak_all = peak * spec.chips if peak else None
+            readings.update(window_compiles=window_compiles, peak_flops=peak_all, trace=reduced)
             metrics = {}
             for m in spec.per_layer:
                 value = spec.reader(m["name"])(readings)

@@ -55,4 +55,4 @@ def test_gat_step_counts_source_rows():
 
 def test_unknown_kind_is_an_error():
     with pytest.raises(ValueError, match="no reference for model kind"):
-        flops.train_step_flops(_sub(), _cfg("hgt"))
+        flops.train_step_flops(_sub(), _cfg("no-such-kind"))

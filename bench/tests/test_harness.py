@@ -1,8 +1,10 @@
-"""The harness finds every cell's files by name, rehearses each cell on
-the CPU at a tiny size, and its check fails where the timed path is
-broken: the control (the reference in bfloat16 in the program's place),
-a step that leaves its state unchanged, one leaf left unmoved, and a loss
-over half the batch."""
+"""The harness finds every cell's files by name, rehearses each one-chip
+cell on the CPU at a tiny size, and its check fails where the timed path
+is broken: the control (the reference in bfloat16 in the program's
+place), a step that leaves its state unchanged, one leaf left unmoved,
+and a loss over half the batch.  Cells on several chips are rehearsed on
+as many virtual CPU devices, in a process of their own
+(``test_train_dp.py``)."""
 import json
 import math
 
@@ -16,6 +18,8 @@ import run
 
 BENCHMARK = json.loads((common.ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCHMARK["workloads"]]
+# the rehearsals in this process, which has one CPU device
+ONE_CHIP = [w["name"] for w in BENCHMARK["workloads"] if w["chips"] == 1]
 
 
 @pytest.fixture(autouse=True)
@@ -57,7 +61,7 @@ def _rehearse(tiny_bench, cell, trace=False, seed=2**31 + 12345):
 
 
 @pytest.mark.parametrize("trace", [False, True])
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", ONE_CHIP)
 def test_rehearsal_on_cpu(tiny_bench, cell, trace):
     res = _rehearse(tiny_bench, cell, trace)
     assert res["correct"] is True, res["checks"]
@@ -88,7 +92,7 @@ def _plant(monkeypatch, after_steps):
     monkeypatch.setattr(common.Spec, "kind", lambda self: kind)
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", ONE_CHIP)
 def test_control_is_not_correct(tiny_bench, monkeypatch, cell):
     def control(self):
         self.prog = self.reference(self.reference_blocks(), dtype=jnp.bfloat16)
@@ -109,7 +113,7 @@ def test_unchanged_state_is_not_correct(tiny_bench, monkeypatch):
     assert res["checks"]["change_gap"]["value"] == pytest.approx(1.0)
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", ONE_CHIP)
 def test_unmoved_leaf_is_not_correct(tiny_bench, monkeypatch, cell):
     """The leaf with the smallest first gradient, left where it started,
     fails the check: the rule that leaves round-off leaves out of

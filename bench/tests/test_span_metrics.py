@@ -32,7 +32,7 @@ def _read(name, r):
 def test_the_span_readers_are_declared():
     assert sorted(SPAN_METRICS) == sorted([
         "train.sampler_ms", "train.build_ms", "train.transit_ms", "train.recv_ms",
-        "train.h2d_ms", "train.producer_busy", "train.batch_mb",
+        "train.h2d_ms", "train.producer_busy", "train.batch_mb", "train.shm_share",
     ])
 
 

@@ -76,3 +76,48 @@ def test_recorded_tpu_trace():
     assert sum(t for _, t in r["breakdown"]["idle_gaps"]) == pytest.approx(
         r["window_s"] - r["busy_s"], rel=1e-6
     )
+
+
+def test_recorded_tpu_trace_reads_as_before():
+    """One chip: the numbers the reduction gave this trace before it
+    averaged over chips."""
+    r = trace_reduce.reduce(RECORDED, "bench.window")
+    assert r["busy_s"] == 2.3713e-05
+    assert r["window_s"] == pytest.approx(0.00998729, rel=1e-12)
+    assert dict(r["breakdown"]["idle_gaps"]) == pytest.approx(
+        {"$time sleep": 0.009963572, trace_reduce.SHORT_GAP: 5e-09}, rel=1e-12
+    )
+
+
+def test_chips_are_averaged(tmp_path):
+    """Three chips: TPU:0 as in the one-chip trace (busy 30 of 100 us),
+    TPU:1 busy 10-20 us, TPU:2 with an operation after the window only.
+    Busy is the mean over the three chips, so one minus busy over the
+    window is the mean of their idle shares; TPU:2 counts as idle
+    throughout."""
+    more = """
+planes {
+  id: 3 name: "/device:TPU:1"
+  lines { id: 1 name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 10000000 duration_ps: 10000000 }
+  }
+  event_metadata { key: 1 value { id: 1 name: "fusion.1" } }
+}
+planes {
+  id: 4 name: "/device:TPU:2"
+  lines { id: 1 name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 100000000 duration_ps: 5000000 }
+  }
+  event_metadata { key: 1 value { id: 1 name: "fusion.1" } }
+}
+"""
+    r = trace_reduce.reduce(_write(tmp_path, HANDMADE + more), "bench.window")
+    assert r["busy_s"] == pytest.approx((30e-6 + 10e-6 + 0.0) / 3)
+    idle = 1 - r["busy_s"] / r["window_s"]
+    assert idle == pytest.approx((0.7 + 0.9 + 1.0) / 3)
+    assert sum(t for _, t in r["breakdown"]["idle_gaps"]) == pytest.approx(
+        r["window_s"] - r["busy_s"], rel=1e-9
+    )
+    assert dict(r["breakdown"]["device_ops"]) == pytest.approx(
+        {"fusion.1": 30e-6 / 3, "convolution.2": 20e-6 / 3}
+    )

@@ -4,11 +4,15 @@ busiest operations and its idle gaps named by what the host was doing.
 The window is the host span the harness opened around the measured call.
 Busy time is the union of the intervals in which an operation ran on a
 device (the device plane's ``XLA Ops`` line), inside the window, averaged
-over the devices that ran anything.  An idle gap is a stretch of the
+over the chips whose trace holds operations; a chip that ran nothing
+inside the window counts as busy for none of it.  So ``1 - busy_s /
+window_s`` is the mean over the chips of each chip's idle share, and on
+one chip that chip's idle share.  An idle gap is a stretch of the
 window in which the device ran nothing; it is named by the shortest host
 event that covers at least half of it (a Python frame or a runtime
 span), else by the host event that overlaps it most; gaps under 20 us
-are counted together.  Idle time is summed by that name.
+are counted together.  Idle time is summed by that name, over the chips,
+and divided by their number, as are the operations' times.
 """
 from __future__ import annotations
 
@@ -75,28 +79,28 @@ def reduce(path: str | Path, window: str, top: int = 10) -> dict | None:
                     host_events.append((ev.start_ns, ev.end_ns, ev.name))
         elif plane.name.startswith("/device:") and not plane.name.startswith("/device:CPU"):
             ops = [l for l in plane.lines if l.name == OPS_LINE]
-            if ops:
-                devices.append([(ev.start_ns, ev.end_ns, _op_name(ev.name)) for ev in ops[0].events])
+            evs = [(ev.start_ns, ev.end_ns, _op_name(ev.name)) for ev in ops[0].events] if ops else []
+            if evs:
+                devices.append(evs)
     if span is None:
         raise ValueError(f"no host span {window!r} in {path}")
     lo, hi = span
     host_events = [h for h in host_events if h[2] != window]
     busy_total, per_op = 0.0, collections.Counter()
     gaps: list[tuple[float, float]] = []
-    used = 0
+    ran = 0  # chips that ran an operation inside the window
     for evs in devices:
         clipped = [(c, n) for s, e, n in evs if (c := _clip(s, e, lo, hi))]
-        if not clipped:
-            continue
-        used += 1
+        ran += bool(clipped)
         for (s, e), n in clipped:
             per_op[n] += (e - s) * 1e-9
         merged = _union([c for c, _ in clipped])
         busy_total += sum(e - s for s, e in merged) * 1e-9
         edges = [lo] + [x for iv in merged for x in iv] + [hi]
         gaps += [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2) if edges[i + 1] > edges[i]]
-    if not used:
+    if not ran:
         return None
+    used = len(devices)
     hs = np.array([h[0] for h in host_events], np.float64)
     he = np.array([h[1] for h in host_events], np.float64)
     names = [h[2] for h in host_events]
