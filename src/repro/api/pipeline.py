@@ -245,8 +245,12 @@ class BatchPipeline:
         feature_source=None,  # FeatureSource; None = graph.vertex_feats
         ticket_timeout: float | None = None,
         worker_respawns: int = 1,
+        vertex_types: bool = False,
     ):
-        """``ticket_timeout`` bounds every blocking ``ticket.result()``
+        """``vertex_types``: each batch carries its rows' vertex types
+        (``GNNBatch.vtype``), for a model that reads them.
+
+        ``ticket_timeout`` bounds every blocking ``ticket.result()``
         wait (None = wait forever, explicitly).  ``worker_respawns`` is the
         crash budget for the forked prefetch worker: a worker found dead
         mid-run is respawned up to this many times, replaying the keyed
@@ -308,6 +312,7 @@ class BatchPipeline:
         )
         self.vertex_quantum = vertex_quantum
         self.edge_quantum = edge_quantum
+        self.vertex_types = vertex_types
         # the training-side feature path: any FeatureSource (e.g. a
         # disk-backed HybridCache) — batches are bit-identical to the
         # in-memory matrix because the cache only changes where rows live
@@ -384,6 +389,8 @@ class BatchPipeline:
                 edge_types=self.graph.edge_types,
                 vertex_quantum=self.vertex_quantum,
                 edge_quantum=self.edge_quantum,
+                vertex_types=self.graph.vertex_types if self.vertex_types else None,
+                index=i,
             )
 
     def _seed_stream(self, epochs: int):

@@ -398,7 +398,7 @@ class LayerwiseInferenceEngine:
         for k, layer_fn in enumerate(self.layer_fns):
             stats = LayerStats()
             slice_fn = self._slice_fn(k, layer_fn)
-            needs_etype = getattr(layer_fn, "needs_etype", False)
+            typed = getattr(layer_fn, "typed", False)
             store_next = DFSTier(
                 f"{self.workdir}/layer{k + 1}",
                 g.num_vertices,
@@ -449,11 +449,11 @@ class LayerwiseInferenceEngine:
                 order = np.argsort(hop.src, kind="stable")
                 h_src_sorted = hop.src[order]
                 h_dst_sorted = hop.dst[order]
-                # edge types are gathered only for layers that consume them
-                # (hgt); other models must not pay for the extra gather
-                if needs_etype and hop.eid is not None:
+                # edge and vertex types are gathered only for layers that
+                # consume them (hgt); other models must not pay for them
+                if typed and hop.eid is not None:
                     h_et_sorted = g.edge_types[hop.eid[order]].astype(np.int32)
-                elif needs_etype:
+                elif typed:
                     h_et_sorted = np.zeros(h_src_sorted.shape[0], np.int32)
                 else:
                     h_et_sorted = None
@@ -482,13 +482,18 @@ class LayerwiseInferenceEngine:
                         if nbr_rows.shape[0]
                         else np.zeros((0, store_prev.dim), store_prev.dtype)
                     )
+                    vt = (
+                        (g.vertex_types[vb], g.vertex_types[nbr_rows])
+                        if typed
+                        else None
+                    )
                     if slice_fn is not None:
                         h_new = self._run_slice(
-                            k, slice_fn, h_self, h_nbr, seg, et, result, stats
+                            k, slice_fn, h_self, h_nbr, seg, et, result, stats, vt
                         )
-                    elif needs_etype:
+                    elif typed:
                         h_new = np.asarray(
-                            layer_fn(k, h_self, h_nbr, seg, et)
+                            layer_fn(k, h_self, h_nbr, seg, et, *vt)
                         )
                     else:
                         h_new = np.asarray(layer_fn(k, h_self, h_nbr, seg))
@@ -506,8 +511,10 @@ class LayerwiseInferenceEngine:
         return result
 
     # -- online serving entry point --------------------------------------
-    def run_layer_batch(self, k, h_self, h_nbr, seg, et=None) -> np.ndarray:
-        """One layer-``k`` slice over an online batch, outside ``run()``.
+    def run_layer_batch(self, k, h_self, h_nbr, seg, et=None, vt=None) -> np.ndarray:
+        """One layer-``k`` slice over an online batch, outside ``run()``;
+        ``et`` and ``vt``, (self rows' vertex types, neighbour rows' vertex
+        types), are for a typed slice.
 
         Shares the offline path's jit cache, bucket ladder, and
         ``_trace_counts``/``_shapes_lifetime`` bookkeeping, so
@@ -518,13 +525,13 @@ class LayerwiseInferenceEngine:
         slice_fn = self._slice_fn(k, layer_fn)
         if slice_fn is not None:
             shim = _ServeSliceStats()
-            return self._run_slice(k, slice_fn, h_self, h_nbr, seg, et, shim)
-        if getattr(layer_fn, "needs_etype", False):
-            return np.asarray(layer_fn(k, h_self, h_nbr, seg, et))
+            return self._run_slice(k, slice_fn, h_self, h_nbr, seg, et, shim, vt=vt)
+        if getattr(layer_fn, "typed", False):
+            return np.asarray(layer_fn(k, h_self, h_nbr, seg, et, *(vt or ())))
         return np.asarray(layer_fn(k, h_self, h_nbr, seg))
 
     # -- bucketed device execution --------------------------------------
-    def _run_slice(self, k, slice_fn, h_self, h_nbr, seg, et, result, stats=None):
+    def _run_slice(self, k, slice_fn, h_self, h_nbr, seg, et, result, stats=None, vt=None):
         """Pad one batch to its (vertex, edge) shape bucket and run the
         jit-compiled slice: one host→device transfer in, one device→host
         readback out.  Shapes repeat across batches, so each (layer, bucket)
@@ -564,7 +571,11 @@ class LayerwiseInferenceEngine:
         etp = np.zeros(ep, np.int32)
         if et is not None:
             etp[:e] = et
-        out = slice_fn(hs, hn, sg, etp)
+        types = ()
+        if vt is not None:
+            types = (np.zeros(bp, np.int32), np.zeros(ep, np.int32))
+            types[0][:b], types[1][:e] = vt
+        out = slice_fn(hs, hn, sg, etp, *types)
         return np.asarray(out[:b])
 
 
@@ -631,8 +642,11 @@ def samplewise_inference(
                 if nbrs.shape[0]
                 else np.zeros((0, h_self.shape[1]), h_self.dtype)
             )
-            if getattr(layer, "needs_etype", False):
-                h_new = np.asarray(layer(k, h_self, h_nbr, seg, et_g))
+            if getattr(layer, "typed", False):
+                h_new = np.asarray(layer(
+                    k, h_self, h_nbr, seg, et_g,
+                    g.vertex_types[need_verts], g.vertex_types[nbrs],
+                ))
             else:
                 h_new = np.asarray(layer(k, h_self, h_nbr, seg))
             nxt = np.zeros((all_verts.shape[0], h_new.shape[1]), h_new.dtype)

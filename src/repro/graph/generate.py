@@ -13,7 +13,24 @@ import numpy as np
 
 from repro.graph.graph import HeteroGraph
 
-__all__ = ["power_law_graph", "erdos_renyi_graph", "named_dataset", "DATASETS"]
+__all__ = [
+    "power_law_graph", "erdos_renyi_graph", "mag240m_graph", "named_dataset", "DATASETS",
+    "MAG240M_EDGE_TYPES", "MAG240M_REVERSE",
+]
+
+# OGB-LSC MAG240M (arXiv:2103.09430): vertices of each type, in type order
+# paper 0, author 1, institution 2
+MAG240M_VERTICES = (121_751_666, 122_383_112, 25_721)
+# its stored edge types (name, source type, destination type): each
+# relation is stored with its reverse, cites in both directions as one type
+MAG240M_EDGE_TYPES = (
+    ("cites", 0, 0),
+    ("writes", 1, 0),
+    ("written_by", 0, 1),
+    ("affiliated_with", 1, 2),
+    ("employs", 2, 1),
+)
+MAG240M_REVERSE = (0, 2, 1, 4, 3)  # the stored type of each type's reverse edge
 
 
 def power_law_graph(
@@ -168,6 +185,89 @@ def erdos_renyi_graph(
             if num_classes
             else None
         ),
+    )
+
+
+def _attach(rng, counts: np.ndarray, num_targets: int, pref: float, same: bool = False):
+    """Preferential attachment as targets arrive: source i (in order) may
+    draw the first ``num_targets * i / len(counts)`` targets (at least one)
+    and draws ``counts[i]`` of them, each with probability ``pref`` from the
+    targets drawn so far (so in proportion to the degree they have gained)
+    and otherwise uniformly.  Where sources and targets are the same
+    vertices (``same``: a paper cites earlier papers) a draw of itself is
+    dropped.  Vectorized in growth batches.  Returns (sources, targets)."""
+    n = counts.shape[0]
+    src = np.repeat(np.arange(n, dtype=np.int64), counts)
+    tgt = np.empty(src.shape[0], np.int64)
+    step = max(256, n // 64)
+    pos = 0
+    for lo in range(0, n, step):
+        m = int(counts[lo: lo + step].sum())
+        s = src[pos: pos + m]
+        arrived = np.maximum(1, s * num_targets // n)
+        draw = (rng.random(m) * arrived).astype(np.int64)
+        if pos:  # earlier draws: each target as often as it was drawn
+            from_pool = rng.random(m) < pref
+            draw[from_pool] = tgt[rng.integers(0, pos, int(from_pool.sum()))]
+        tgt[pos: pos + m] = draw
+        pos += m
+    keep = src != tgt if same else slice(None)
+    return src[keep], tgt[keep]
+
+
+def mag240m_graph(
+    num_vertices: int,
+    feat_dim: int = 768,
+    num_classes: int = 153,
+    seed: int = 0,
+) -> HeteroGraph:
+    """A typed graph of OGB-LSC MAG240M's shape (arXiv:2103.09430), scaled
+    to ``num_vertices``: papers, authors and institutions in MAG240M's
+    shares (ids in that order), and its published average degrees: each
+    paper cites 10.66 earlier papers (Poisson) and has 1 + Poisson(2.17)
+    authors, each author 0.364 institutions (Poisson).  Cited papers,
+    a paper's authors and an author's institutions are drawn by
+    preferential attachment as they arrive (``_attach``), so cites
+    in-degree, papers per author and authors per institution have heavy
+    tails.  The stored types are ``MAG240M_EDGE_TYPES``: every citation in
+    both directions, writes and affiliated_with each with its reverse.  Features are ``feat_dim``-wide
+    float32 for every vertex; labels in 0..num_classes-1 on papers, -1 on
+    the rest (MAG240M labels papers only)."""
+    rng = np.random.default_rng(seed)
+    total = sum(MAG240M_VERTICES)
+    n_paper = round(num_vertices * MAG240M_VERTICES[0] / total)
+    n_inst = max(1, round(num_vertices * MAG240M_VERTICES[2] / total))
+    n_author = num_vertices - n_paper - n_inst
+    first_author, first_inst = n_paper, n_paper + n_author
+
+    citing, cited = _attach(rng, rng.poisson(10.66, n_paper), n_paper, 0.75, same=True)
+    paper, author = _attach(rng, 1 + rng.poisson(2.17, n_paper), n_author, 0.6)
+    author += first_author
+    member, inst = _attach(rng, rng.poisson(0.364, n_author), n_inst, 0.6)
+    member += first_author
+    inst += first_inst
+    parts = [  # (source, destination) of each stored type, in type order
+        (np.concatenate([citing, cited]), np.concatenate([cited, citing])),
+        (author, paper),
+        (paper, author),
+        (member, inst),
+        (inst, member),
+    ]
+    src = np.concatenate([s for s, _ in parts])
+    dst = np.concatenate([d for _, d in parts])
+    et = np.repeat(np.arange(len(parts), dtype=np.int16), [s.shape[0] for s, _ in parts])
+    vt = np.repeat(np.arange(3, dtype=np.int16), [n_paper, n_author, n_inst])
+    labels = np.full(num_vertices, -1, np.int32)
+    labels[:n_paper] = rng.integers(0, num_classes, n_paper)
+    return HeteroGraph(
+        num_vertices=num_vertices,
+        src=src,
+        dst=dst,
+        edge_types=et,
+        vertex_types=vt,
+        edge_weights=rng.gamma(2.0, 1.0, size=src.shape[0]).astype(np.float32),
+        vertex_feats=rng.standard_normal((num_vertices, feat_dim), dtype=np.float32),
+        labels=labels,
     )
 
 

@@ -15,6 +15,7 @@ from dataclasses import dataclass
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core.sampling.service import SampledSubgraph
 from repro.core.storage import as_feature_source
 from repro.utils import round_up
@@ -37,6 +38,9 @@ class GNNBatch:
     # static for the batch, so it's counted ONCE here (host-side bincount)
     # instead of once per GCN/SAGE layer call; None = compute in-model
     layer_cnt: list | None = None
+    # [V] int32 vertex type of each row (0 on padding), for models that
+    # read it (hgt); None = not carried
+    vtype: np.ndarray | None = None
 
     @property
     def num_vertices(self) -> int:
@@ -56,7 +60,13 @@ def subgraph_to_batch(
     edge_types: np.ndarray | None = None,  # global per-edge type table
     vertex_quantum: int = 256,
     edge_quantum: int = 1024,
+    vertex_types: np.ndarray | None = None,  # global per-vertex type table
+    index: int | None = None,  # the batch's request index, for its counters
 ) -> GNNBatch:
+    """``vertex_types`` given, the batch carries its rows' types
+    (``GNNBatch.vtype``).  While ``repro.obs`` records, the batch's real
+    sampled edges and its padded edge slots, each summed over the layers,
+    are counted as ``batch.edges`` and ``batch.edge_slots``."""
     src = as_feature_source(feats)
     verts = sub.all_vertices()  # unique sorted gids
     vpad = _bucket(verts.shape[0], vertex_quantum)
@@ -64,6 +74,10 @@ def subgraph_to_batch(
     table[: verts.shape[0]] = src.gather(verts)
     valid = np.zeros(vpad, dtype=bool)
     valid[: verts.shape[0]] = True
+    vtype = None
+    if vertex_types is not None:
+        vtype = np.zeros(vpad, dtype=np.int32)
+        vtype[: verts.shape[0]] = vertex_types[verts]
 
     seed_pos = np.searchsorted(verts, sub.seeds).astype(np.int32)
     lab = (
@@ -103,6 +117,9 @@ def subgraph_to_batch(
             .astype(np.float32)
             .reshape(vpad, 1)
         )
+    if obs.tracing():
+        obs.count("batch.edges", sum(int((d >= 0).sum()) for d in layer_dst), index)
+        obs.count("batch.edge_slots", sum(d.shape[0] for d in layer_dst), index)
     return GNNBatch(
         feats=table,
         valid=valid,
@@ -112,4 +129,5 @@ def subgraph_to_batch(
         layer_src=layer_src,
         layer_etype=layer_et,
         layer_cnt=layer_cnt,
+        vtype=vtype,
     )

@@ -5,6 +5,20 @@ the RelNet KGE encoder is a 2-layer HGT).
 All layers aggregate over padded edge lists (dst_pos, src_pos, etype) with
 -1 padding; the segment-sum hotspot goes through kernels.gnn_aggregate
 (Pallas on TPU, jnp oracle otherwise).
+
+HGT is the Heterogeneous Graph Transformer (Hu et al., WWW 2020,
+arXiv:2003.01332) as PyG's ``HGTConv`` has it (eq. 1-5, no relative
+temporal encoding), after a per-type input linear and tanh (pyHGT's
+``adapt_ws``), which layer 0 carries.  With τ a row's vertex type and φ an
+edge's relation (the stored type of the sampled edge), a layer over H heads of width d computes
+``q_t = Q_τ(t) h_t``, ``k_s = K_τ(s) h_s``, ``v_s = V_τ(s) h_s`` (linears
+with bias), per edge s -> t and head i ``a = μ_φ,i (q_t,i · k_s,i W^ATT_φ,i)
+/ √d`` and the message ``v_s,i W^MSG_φ,i``, a softmax of ``a`` per head over
+all of t's in-edges, across relations, ``o_t = A_τ(t) gelu(sum α m)`` and
+``h'_t = β o_t + (1 - β) h_t`` with ``β = sigmoid(s_τ(t))``.  Per-type and
+per-relation weights are applied as dense products over the rows, each
+row or edge taking its own type's result: no array indexed by edge holds
+a weight matrix.
 """
 from __future__ import annotations
 
@@ -60,6 +74,22 @@ def _seg_softmax(logits, seg, n, use_kernel=False):
     return e / jnp.maximum(z[jnp.maximum(seg, 0)], 1e-9)
 
 
+def _typed_linear(x, w, b, vt):
+    """``x @ w[t] + b[t]`` for each row's type ``t`` (w: [T, din, dout]):
+    every type's product over all rows, each row keeping its own."""
+    out = x @ w[0] + b[0]
+    for t in range(1, w.shape[0]):
+        out = jnp.where((vt == t)[:, None], x @ w[t] + b[t], out)
+    return out
+
+
+def _per_relation(x, w, src, rel):
+    """``x[src[e], i] @ w[rel[e], i]`` for each edge e and head i (x: [n,
+    H, d], w: [R, H, d, d]): every relation's product over the rows, and
+    each edge gathers its source row's under its own relation."""
+    return jnp.einsum("nhd,rhdf->nrhf", x, w)[src, rel]
+
+
 def _seg_softmax_aggregate(logits, msg, seg, n, use_kernel):
     """out[s] = sum_e softmax_{seg==s}(logits)[e] * msg[e] — the GAT/HGT
     per-head inner loop.  One Pallas kernel when enabled, the original
@@ -81,7 +111,10 @@ class GNNModel:
         num_heads: int = 4,
         num_etypes: int = 4,
         use_kernel: bool = False,
+        num_vtypes: int = 1,
     ):
+        """hgt only: ``num_vtypes`` vertex types and ``num_etypes``
+        relations, a relation being the stored type of the sampled edge."""
         assert kind in GNN_KINDS
         self.kind = kind
         self.in_dim = in_dim
@@ -91,6 +124,12 @@ class GNNModel:
         self.num_heads = num_heads
         self.num_etypes = num_etypes
         self.use_kernel = use_kernel
+        self.num_vtypes = num_vtypes
+
+    @property
+    def reads_vertex_types(self) -> bool:
+        """Whether a batch must carry its rows' vertex types (``GNNBatch.vtype``)."""
+        return self.kind == "hgt"
 
     # -- init ---------------------------------------------------------------
     def init(self, key) -> Params:
@@ -116,16 +155,7 @@ class GNNModel:
                     "a_src": jax.random.normal(k3, (h, dh)) * 0.1,
                 }
             elif self.kind == "hgt":
-                h, e = self.num_heads, self.num_etypes
-                dh = dout // h
-                k1, k2, k3, k4, k5 = jax.random.split(lk, 5)
-                p = {
-                    "wq": jax.random.normal(k1, (din, h * dh)) * scale,
-                    "wk": jax.random.normal(k2, (e, din, h * dh)) * scale,
-                    "wv": jax.random.normal(k3, (e, din, h * dh)) * scale,
-                    "wo": jax.random.normal(k4, (h * dh, dout)) * scale,
-                    "wskip": jax.random.normal(k5, (din, dout)) * scale,
-                }
+                p = self._hgt_init(lk, din if k == 0 else None)
             layers.append(p)
         ko = jax.random.fold_in(key, 999)
         return {
@@ -134,14 +164,74 @@ class GNNModel:
             * (1.0 / self.hidden) ** 0.5,
         }
 
+    def _hgt_init(self, key, in_dim: int | None) -> Params:
+        """One HGT layer from ``key``: per-type Q, K, V and output linears
+        ``N(0, 1/hidden)`` with zero bias; per relation and head the
+        attention and message matrices ``N(0, 1/d)``; the prior μ and per
+        type the gate s at 1 (as PyG); with ``in_dim`` (layer 0) the
+        per-type input adapter from the features, ``N(0, 1/in_dim)``."""
+        T, R, H = self.num_vtypes, self.num_etypes, self.num_heads
+        D, d = self.hidden, self.hidden // self.num_heads
+        ks = jax.random.split(key, 7)
+        lin = lambda kk, n_in: jax.random.normal(kk, (T, n_in, D)) * (1.0 / n_in) ** 0.5
+        rel = lambda kk: jax.random.normal(kk, (R, H, d, d)) * (1.0 / d) ** 0.5
+        p = {
+            "wq": lin(ks[0], D), "bq": jnp.zeros((T, D)),
+            "wk": lin(ks[1], D), "bk": jnp.zeros((T, D)),
+            "wv": lin(ks[2], D), "bv": jnp.zeros((T, D)),
+            "wa": lin(ks[3], D), "ba": jnp.zeros((T, D)),
+            "w_att": rel(ks[4]), "w_msg": rel(ks[5]),
+            "mu": jnp.ones((R, H)), "skip": jnp.ones((T,)),
+        }
+        if in_dim is not None:
+            p["w_in"], p["b_in"] = lin(ks[6], in_dim), jnp.zeros((T, D))
+        return p
+
+    def _hgt_layer(self, p: Params, h, dst, src, etype, vt, use_kernel: bool):
+        """One HGT layer (module docstring) over rows ``h`` of vertex types
+        ``vt`` and the padded edge list; ``vt`` may be None only where there
+        is one vertex type."""
+        n, heads = h.shape[0], self.num_heads
+        if vt is None:
+            if self.num_vtypes > 1:
+                raise ValueError(
+                    f"hgt over {self.num_vtypes} vertex types needs each row's type"
+                )
+            vt = jnp.zeros(n, jnp.int32)
+        if "w_in" in p:  # layer 0: the input adapter
+            with jax.named_scope("hgt.typed_linear"):
+                h = jnp.tanh(_typed_linear(h, p["w_in"], p["b_in"], vt))
+        d = h.shape[1] // heads
+        with jax.named_scope("hgt.typed_linear"):
+            q, k, v = (
+                _typed_linear(h, p[w], p[b], vt).reshape(n, heads, d)
+                for w, b in (("wq", "bq"), ("wk", "bk"), ("wv", "bv"))
+            )
+        with jax.named_scope("hgt.relation"):
+            rel = jnp.maximum(etype, 0)
+            s = jnp.maximum(src, 0)
+            k_e = _per_relation(k, p["w_att"], s, rel)
+            m_e = jnp.where((src >= 0)[:, None, None], _per_relation(v, p["w_msg"], s, rel), 0.0)
+        with jax.named_scope("hgt.attention"):
+            att = (q[jnp.maximum(dst, 0)] * k_e).sum(-1) * p["mu"][rel] / d**0.5  # [E, H]
+            agg = jnp.concatenate(
+                [_seg_softmax_aggregate(att[:, i], m_e[:, i], dst, n, use_kernel) for i in range(heads)],
+                axis=1,
+            )
+        with jax.named_scope("hgt.output"):
+            o = _typed_linear(jax.nn.gelu(agg, approximate=False), p["wa"], p["ba"], vt)
+            beta = jax.nn.sigmoid(p["skip"][vt])[:, None]
+            return beta * o + (1.0 - beta) * h
+
     # -- single layer ---------------------------------------------------------
     def layer(
-        self, p: Params, k: int, h: jax.Array, dst, src, etype, cnt=None
+        self, p: Params, k: int, h: jax.Array, dst, src, etype, cnt=None, vtype=None
     ) -> jax.Array:
         """``cnt`` is the optional precomputed in-degree column ([n, 1],
         valid-edge counts per destination) — static per batch, so callers
         with a ``GNNBatch.layer_cnt`` pass it instead of recomputing the
-        segment-count here on every layer call."""
+        segment-count here on every layer call.  ``vtype`` is each row's
+        vertex type, read by hgt."""
         n = h.shape[0]
         ok = src >= 0
         if self.kind in ("gcn", "sage"):
@@ -172,27 +262,7 @@ class GNNModel:
                 )
             return jax.nn.elu(jnp.concatenate(out, axis=1))
         if self.kind == "hgt":
-            heads = self.num_heads
-            dout = p["wo"].shape[0] // heads
-            q = (h @ p["wq"]).reshape(n, heads, dout)
-            wk = p["wk"][etype]  # [E, din, h*dh]
-            wv = p["wv"][etype]
-            ke = jnp.einsum("ed,edf->ef", h[jnp.maximum(src, 0)], wk).reshape(
-                -1, heads, dout
-            )
-            ve = jnp.einsum("ed,edf->ef", h[jnp.maximum(src, 0)], wv).reshape(
-                -1, heads, dout
-            )
-            qd = q[jnp.maximum(dst, 0)]
-            att = (qd * ke).sum(-1) / (dout**0.5)  # [E, H]
-            out = []
-            for hd in range(heads):
-                msg = jnp.where(ok[:, None], ve[:, hd], 0.0)
-                out.append(
-                    _seg_softmax_aggregate(att[:, hd], msg, dst, n, self.use_kernel)
-                )
-            agg = jnp.concatenate(out, axis=1) @ p["wo"]
-            return jax.nn.gelu(agg + h @ p["wskip"])
+            return self._hgt_layer(p, h, dst, src, etype, vtype, self.use_kernel)
         raise ValueError(self.kind)
 
     # -- full apply --------------------------------------------------------------
@@ -212,6 +282,7 @@ class GNNModel:
                 batch.layer_src[k],
                 batch.layer_etype[k],
                 cnt=None if cnts is None else cnts[k],
+                vtype=getattr(batch, "vtype", None),
             )
             h = h * batch.valid[:, None]
         return h[batch.seed_pos] @ params["out"]
@@ -224,16 +295,19 @@ class GNNModel:
 
     def embed_layer_fn(self, params: Params, k: int, *, use_kernel: bool | None = None):
         """Adapter for the layerwise inference engine: one slice of the model
-        as (k, h_self, h_nbr, seg[, etype]) -> h_new (numpy in/out).
+        as (k, h_self, h_nbr, seg[, etype[, vt_self, vt_nbr]]) -> h_new
+        (numpy in/out).
 
         The returned callable carries two engine-facing attributes:
 
-        * ``fn.jax(h_self, h_nbr, seg, etype, *, use_kernel=...)`` — the pure
-          traceable slice on jnp arrays with ``seg == -1`` padding, which the
-          bucketed engine wraps in ``jax.jit`` so each (layer, shape-bucket)
-          pair compiles once and stays device-resident.
-        * ``fn.needs_etype`` — True for hgt, whose per-edge relation
-          projections need the sampled edges' type ids.
+        * ``fn.jax(h_self, h_nbr, seg, etype[, vt_self, vt_nbr], *,
+          use_kernel=...)`` — the pure traceable slice on jnp arrays with
+          ``seg == -1`` padding, which the bucketed engine wraps in
+          ``jax.jit`` so each (layer, shape-bucket) pair compiles once and
+          stays device-resident.
+        * ``fn.typed`` — True for hgt, whose per-relation and per-type
+          projections need the sampled edges' type ids and the vertex types
+          of the self rows and of the neighbour rows.
 
         Covers all four evaluated kinds (gcn/sage/gat/hgt); aggregation goes
         through :func:`repro.kernels.ops.gnn_aggregate` when ``use_kernel``
@@ -243,7 +317,7 @@ class GNNModel:
         heads = self.num_heads
         default_kernel = self.use_kernel if use_kernel is None else use_kernel
 
-        def jax_fn(h_self, h_nbr, seg, etype, *, use_kernel=default_kernel):
+        def jax_fn(h_self, h_nbr, seg, etype, vt_self=None, vt_nbr=None, *, use_kernel=default_kernel):
             n = h_self.shape[0]
             seg = seg.astype(jnp.int32)
             ok = seg >= 0
@@ -273,26 +347,16 @@ class GNNModel:
                     )
                 return jax.nn.elu(jnp.concatenate(out, axis=1))
             if kind == "hgt":
-                dout = p["wo"].shape[0] // heads
-                q = (h_self @ p["wq"]).reshape(n, heads, dout)
-                et = jnp.maximum(etype.astype(jnp.int32), 0)
-                wk = p["wk"][et]  # [E, din, h*dh]
-                wv = p["wv"][et]
-                ke = jnp.einsum("ed,edf->ef", h_nbr, wk).reshape(-1, heads, dout)
-                ve = jnp.einsum("ed,edf->ef", h_nbr, wv).reshape(-1, heads, dout)
-                qd = q[jnp.maximum(seg, 0)]
-                att = (qd * ke).sum(-1) / (dout**0.5)  # [E, H]
-                out = []
-                for hd in range(heads):
-                    msg = jnp.where(ok[:, None], ve[:, hd], 0.0)
-                    out.append(
-                        _seg_softmax_aggregate(att[:, hd], msg, seg, n, use_kernel)
-                    )
-                agg = jnp.concatenate(out, axis=1) @ p["wo"]
-                return jax.nn.gelu(agg + h_self @ p["wskip"])
+                # the layer itself over the self rows followed by one row
+                # per neighbour edge; only the self rows' outputs are kept
+                m, e = h_self.shape[0], h_nbr.shape[0]
+                src = jnp.where(ok, m + jnp.arange(e, dtype=jnp.int32), -1)
+                vt = None if vt_self is None else jnp.concatenate([vt_self, vt_nbr]).astype(jnp.int32)
+                h = jnp.concatenate([h_self, h_nbr])
+                return self._hgt_layer(p, h, seg, src, etype.astype(jnp.int32), vt, use_kernel)[:m]
             raise ValueError(kind)
 
-        def fn(_k, h_self, h_nbr, seg, etype=None):
+        def fn(_k, h_self, h_nbr, seg, etype=None, vt_self=None, vt_nbr=None):
             m = h_nbr.shape[0]
             sg = jnp.asarray(seg, jnp.int32) if m else jnp.zeros(0, jnp.int32)
             et = (
@@ -300,8 +364,9 @@ class GNNModel:
                 if etype is not None and m
                 else jnp.zeros(m, jnp.int32)
             )
+            types = [None if t is None else jnp.asarray(t, jnp.int32) for t in (vt_self, vt_nbr)]
             return jax.device_get(
-                jax_fn(jnp.asarray(h_self), jnp.asarray(h_nbr), sg, et)
+                jax_fn(jnp.asarray(h_self), jnp.asarray(h_nbr), sg, et, *types)
             )
 
         def kernel_shapes(num_edges, num_vertices, in_dim):
@@ -313,10 +378,10 @@ class GNNModel:
                     ("segment_spmm_ragged", (num_edges, num_vertices, in_dim)),
                     ("segment_spmm_ragged", (num_edges, num_vertices, 1)),
                 ]
-            dh = p["a_dst"].shape[1] if kind == "gat" else p["wo"].shape[0] // heads
+            dh = p["a_dst"].shape[1] if kind == "gat" else self.hidden // heads
             return [("gat_softmax_aggregate", (num_edges, num_vertices, dh))]
 
         fn.jax = jax_fn
-        fn.needs_etype = kind == "hgt"
+        fn.typed = self.reads_vertex_types
         fn.kernel_shapes = kernel_shapes
         return fn

@@ -88,7 +88,7 @@ class GNNServer:
         self.spec = SamplingSpec(
             fanouts=(engine.fanouts[k],), direction=engine.direction
         )
-        self._needs_etype = getattr(engine.layer_fns[k], "needs_etype", False)
+        self._typed = getattr(engine.layer_fns[k], "typed", False)
         self.queue = RequestQueue(queue_depth)
         self.batcher = ContinuousBatcher(engine.batch_size, max_batch_delay_ms)
         self.stats = ServeStats()
@@ -230,7 +230,7 @@ class GNNServer:
         base offset and the padded slice is row-independent, so each
         request's output rows are bit-identical to a solo run."""
         engine, g = self.engine, self.system.graph
-        selfs, nbrs, segs, ets, metas = [], [], [], [], []
+        selfs, nbrs, segs, ets, vts, metas = [], [], [], [], [], []
         base = 0
         for req, sub in live:
             verts = req.unique
@@ -240,12 +240,13 @@ class GNNServer:
             starts = np.searchsorted(src, verts)
             counts = np.searchsorted(src, verts, side="right") - starts
             nbr_ids = csr_gather(dst, starts, counts)
-            if self._needs_etype:
+            if self._typed:
                 if hop.eid is not None:
                     et_sorted = g.edge_types[hop.eid[order]].astype(np.int32)
                 else:
                     et_sorted = np.zeros(src.shape[0], np.int32)
                 ets.append(csr_gather(et_sorted, starts, counts))
+                vts.append((g.vertex_types[verts], g.vertex_types[nbr_ids]))
             selfs.append(self.cache.read_rows(self.newid[verts]))
             nbrs.append(
                 self.cache.read_rows(self.newid[nbr_ids])
@@ -259,7 +260,12 @@ class GNNServer:
         h_nbr = np.concatenate(nbrs)
         seg = np.concatenate(segs).astype(np.int64)
         et = np.concatenate(ets).astype(np.int32) if ets else None
-        h_new = engine.run_layer_batch(self.layer, h_self, h_nbr, seg, et)
+        vt = (
+            tuple(np.concatenate([v[i] for v in vts]) for i in (0, 1))
+            if vts
+            else None
+        )
+        h_new = engine.run_layer_batch(self.layer, h_self, h_nbr, seg, et, vt)
         self.stats.note_batch(
             h_self.shape[0],
             engine._vertex_bucket(h_self.shape[0]),

@@ -116,6 +116,11 @@ def stack_batches(batches: list[GNNBatch]) -> GNNBatch:
             if all(b.layer_cnt is not None for b in batches)
             else None
         ),
+        vtype=(
+            np.stack([pad0(b.vtype, vmax, 0) for b in batches])
+            if all(b.vtype is not None for b in batches)
+            else None
+        ),
     )
 
 
@@ -173,6 +178,7 @@ class DataParallelGNNTrainer:
                 vertex_quantum=vertex_quantum,
                 edge_quantum=edge_quantum,
                 ticket_timeout=ticket_timeout,
+                vertex_types=model.reads_vertex_types,
             )
             for i in range(self.num_shards)
         ]

@@ -93,6 +93,7 @@ class GNNTrainer:
             feature_source=feature_source,
             ticket_timeout=ticket_timeout,
             worker_respawns=worker_respawns,
+            vertex_types=model.reads_vertex_types,
         )
         self.fanouts = self.pipeline.fanouts
         self.direction = self.pipeline.direction
