@@ -24,18 +24,20 @@ Two worker modes:
     sections (numpy only releases the GIL for a handful of ops), so a
     separate process is the only way host sampling truly runs beside XLA
     compute — the same reason DGL/PyTorch dataloaders use worker processes.
-    The batch's arrays do not cross the queue's pipe: the worker copies
-    them into one of ``prefetch + 2`` shared-memory slots (anonymous
-    ``memfd`` files made before the fork; what the queue holds, the one the
-    consumer holds, the one being filled) and queues only a small message,
-    from which the consumer rebuilds the arrays as views of the slot.  A
-    slot grows to the largest batch it has carried, so the slots cost
-    ``prefetch + 2`` batches of host memory.  The consumer returns a slot
-    when it asks for the next batch, once the copy to the device has read
-    it; on the CPU backend, where ``jnp.asarray`` may alias host memory,
-    the staged arrays are copied out of the slot first.  Where no slot can
-    be had (no ``memfd``, or a slot cannot grow) the batch goes pickled
-    through the queue instead.
+    The batch's arrays do not cross the queue's pipe: the worker builds
+    each batch straight into one of ``prefetch + 2`` shared-memory slots
+    (anonymous ``memfd`` files made before the fork; what the queue holds,
+    the one the consumer holds, the one being filled), gathering the
+    feature rows into it with no private table and no copy, and queues
+    only a small message, from which the consumer rebuilds the arrays as
+    views of the slot.  A slot grows to the largest batch it has carried,
+    so the slots cost ``prefetch + 2`` batches of host memory.  The
+    consumer returns a slot when it asks for the next batch, once the copy
+    to the device has read it; on the CPU backend, where ``jnp.asarray``
+    may alias host memory, the staged arrays are copied out of the slot
+    first.  Where no slot can be had (no ``memfd``, or a slot cannot grow)
+    the batch is built in fresh arrays and goes pickled through the queue
+    instead, as in thread mode and with ``prefetch=0``.
 ``thread`` — in-process double buffering via a daemon thread.  Zero-copy
     hand-off, but overlap is limited to the consumer's GIL-released windows.
 
@@ -50,16 +52,18 @@ Tracing: while JAX's profiler traces, each batch's trip is recorded in
 ``repro.obs`` under its request index: ``sample.submit``, ``sample.wait``,
 ``batch.build``, ``batch.bytes`` and ``batch.put`` in the producer (the
 forked worker records on the flag of its ``produce`` command and ships its
-records in the batches' messages; its ``batch.put`` is the wait for a free
-slot, the copy into it and the queue put, and its ``batch.shm_bytes`` the
-bytes that went through a slot, 0 for a pickled batch), ``pipeline.recv``
-and ``pipeline.h2d`` in the consumer.  ``prefetch=0`` has no queue, so no
-put or recv.
+records in the batches' messages; between its ``sample.wait`` and its
+``batch.build`` it records ``batch.slot``, the wait for a free slot, which
+is no work and is kept off ``sample_time``; its ``batch.put`` is the queue
+put of the batch's message, and its ``batch.shm_bytes`` the bytes built in
+a slot, 0 for a pickled batch), ``pipeline.recv`` and ``pipeline.h2d`` in
+the consumer.  ``prefetch=0`` has no queue, so no put or recv.
 """
 from __future__ import annotations
 
 import collections
 import logging
+import math
 import mmap
 import multiprocessing as mp
 import os
@@ -110,19 +114,28 @@ class _Slot(NamedTuple):
     leaves: list  # (offset, shape, dtype) per array leaf
 
 
+class _Cancelled(Exception):
+    """The worker's run was cancelled while it waited for a free slot."""
+
+
 class _SlotRing:
-    """Shared-memory slots that carry the forked worker's batches to the
-    trainer, so that only a small message crosses the queue's pipe.
+    """Shared-memory slots that the forked worker builds its batches in and
+    that carry them to the trainer, so that only a small message crosses
+    the queue's pipe.
 
     Each slot is an anonymous memory file (``memfd``) made before the fork:
     the worker inherits its descriptor, and no name exists that could be
     left behind or that ``resource_tracker`` would register.  The memory is
     freed once both processes have closed the descriptor and unmapped it.
-    The worker regrows a slot (``ftruncate``) when a batch is larger than
-    it, so slot sizes follow the observed batch size.  Free slot ids flow
-    back to the worker through a pipe; the worker starts holding them all.
-    Where no ``memfd`` exists, or a slot cannot grow, batches go through
-    the pickled queue pipe instead.
+    For each batch the worker claims a free slot (:meth:`claim`), hands the
+    build the slot's memory as arrays of the batch's layout (:meth:`place`),
+    regrowing the slot (``ftruncate``) when the batch is larger than it, so
+    slot sizes follow the observed batch size, and sends the message
+    :meth:`handoff` gives.  A slot stays mapped in each process while its
+    size holds, so its pages are faulted once, not per batch.  Free slot
+    ids flow back to the worker through a pipe; the worker starts holding
+    them all.  Where no ``memfd`` exists, or a slot cannot grow, the batch
+    is built in fresh arrays and goes through the pickled queue pipe.
     """
 
     def __init__(self, n: int, ctx):
@@ -134,6 +147,8 @@ class _SlotRing:
         self.maps: list = [None] * n  # this process's mapping of each slot
         self._free_r, self._free_w = ctx.Pipe(duplex=False)
         self._idle = collections.deque(range(n))  # worker side: free slot ids
+        self._held = None  # worker side: the slot claimed for the next batch
+        self._placed = None  # worker side: its _Slot, once the build has it
 
     def _map(self, slot: int, size: int) -> mmap.mmap:
         mm = self.maps[slot]
@@ -142,30 +157,30 @@ class _SlotRing:
             self.maps[slot] = mm = mmap.mmap(self.fds[slot], size)
         return mm
 
-    def _acquire(self, cancel) -> int | None:
+    def claim(self, cancel) -> None:
+        """Worker side: wait for a free slot and hold it for the next
+        batch.  Raises :class:`_Cancelled` where the run is cancelled
+        first."""
         if self._idle:
-            return self._idle.popleft()
+            self._held = self._idle.popleft()
+            return
         while not self._free_r.poll(0.05):
             if cancel.is_set():
-                return None
-        return self._free_r.recv()
+                raise _Cancelled
+        self._held = self._free_r.recv()
 
-    def pack(self, batch: GNNBatch, cancel) -> tuple:
-        """Worker side: ``(payload, bytes placed)``.  The payload is a
-        :class:`_Slot` once the batch's arrays are copied into a free slot,
-        the batch itself where it cannot be placed, or None where the run
-        was cancelled while waiting for a free slot."""
-        leaves, tree = jax.tree.flatten(batch)
-        if self.fds is None or not all(isinstance(x, np.ndarray) for x in leaves):
-            return batch, 0
+    def place(self, layout: GNNBatch) -> GNNBatch | None:
+        """Worker side: the held slot's memory as arrays of ``layout``'s
+        shapes and dtypes (a :class:`GNNBatch` of ``jax.ShapeDtypeStruct``),
+        for the batch to be built in; None where the slot cannot grow to
+        fit, and the batch is then built in fresh arrays."""
+        leaves, tree = jax.tree.flatten(layout)
         metas, end = [], 0
         for x in leaves:
             off = -(-end // _ALIGN) * _ALIGN
             metas.append((off, x.shape, x.dtype))
-            end = off + x.nbytes
-        slot = self._acquire(cancel)
-        if slot is None:
-            return None, 0
+            end = off + math.prod(x.shape) * x.dtype.itemsize
+        slot = self._held
         size = -(-max(end, 1) // mmap.PAGESIZE) * mmap.PAGESIZE
         try:
             if self.maps[slot] is None or len(self.maps[slot]) < size:
@@ -173,11 +188,19 @@ class _SlotRing:
             mm = self._map(slot, size)
         except OSError as exc:  # the slot cannot grow: send it pickled
             _log.warning("batch of %d bytes goes through the pickled queue: %s", end, exc)
+            return None
+        self._placed = _Slot(slot, len(mm), tree, metas)
+        return jax.tree.unflatten(tree, [np.ndarray(s, d, mm, off) for off, s, d in metas])
+
+    def handoff(self) -> _Slot | None:
+        """Worker side: the message for the batch built in the held slot,
+        or None where it was built elsewhere (the slot is then free
+        again).  The slot is no longer held."""
+        slot, msg = self._held, self._placed
+        self._held = self._placed = None
+        if msg is None and slot is not None:
             self._idle.append(slot)
-            return batch, 0
-        for x, (off, shape, dtype) in zip(leaves, metas):
-            np.ndarray(shape, dtype, mm, off)[...] = x
-        return _Slot(slot, len(mm), tree, metas), sum(x.nbytes for x in leaves)
+        return msg
 
     def unpack(self, msg: _Slot) -> GNNBatch:
         """Trainer side: the batch's arrays as numpy views of its slot."""
@@ -341,6 +364,7 @@ class BatchPipeline:
         self._data_q = None
         self._cancel = None  # mp.Event: stop the worker's current run early
         self._ring = None  # _SlotRing: the worker's batches, in shared memory
+        self._slots = None  # the ring, in the forked worker that builds in it
         self._run_history: list[int] = []  # epochs of fully produced runs
 
     # ------------------------------------------------------------------
@@ -378,8 +402,18 @@ class BatchPipeline:
             return i, ticket.result(timeout=self.ticket_timeout)
 
     def make_batch(self, seeds: np.ndarray) -> GNNBatch:
-        """One seed batch through sampling + padding (numpy, no prefetch)."""
+        """One seed batch through sampling + padding (numpy, no prefetch).
+        In the forked worker the batch is built in a free shared-memory
+        slot, claimed once the sample is in (span ``batch.slot``)."""
         i, sub = self._take_sample(seeds)
+        into = None
+        if self._slots is not None:
+            t0 = time.perf_counter()
+            with obs.span("batch.slot", i):
+                self._slots.claim(self._cancel)
+            # a wait, not work: off the producer clock _produce_np runs
+            self.sample_time -= time.perf_counter() - t0
+            into = self._slots.place
         with obs.span("batch.build", i):
             return subgraph_to_batch(
                 sub,
@@ -391,6 +425,7 @@ class BatchPipeline:
                 edge_quantum=self.edge_quantum,
                 vertex_types=self.graph.vertex_types if self.vertex_types else None,
                 index=i,
+                into=into,
             )
 
     def _seed_stream(self, epochs: int):
@@ -465,6 +500,8 @@ class BatchPipeline:
                 if obs.tracing():
                     obs.count("batch.bytes", sum(x.nbytes for x in jax.tree.leaves(batch)), i)
                 yield i, seeds, batch
+        except _Cancelled:  # stopped while the batch waited for a slot
+            return
         finally:
             self._drop_pending()
 
@@ -518,6 +555,8 @@ class BatchPipeline:
 
     # -- process-mode plumbing -----------------------------------------
     def _worker_loop(self):  # runs in the forked child: numpy only, no XLA
+        if self._ring.fds is not None:
+            self._slots = self._ring  # make_batch builds in its slots
         while True:
             # glint: disable=PRJ004 -- SimpleQueue has no timeout kwarg; an
             # idle worker is stopped via close(), which escalates to kill()
@@ -534,13 +573,15 @@ class BatchPipeline:
             obs.forked(tracing)
             try:
                 for i, seeds, batch in self._produce_np(epochs, skip=skip):
+                    msg = self._ring.handoff()  # None: built in fresh arrays
+                    if msg is None:
+                        payload, placed = batch, 0
+                    else:
+                        payload, placed = msg, sum(x.nbytes for x in jax.tree.leaves(batch))
+                    obs.count("batch.shm_bytes", placed, i)
                     # the message carries what was recorded before this put;
                     # the put's own span rides with the next one
                     with obs.span("batch.put", i):
-                        payload, placed = self._ring.pack(batch, self._cancel)
-                        if payload is None:  # cancelled waiting for a slot
-                            break
-                        obs.count("batch.shm_bytes", placed, i)
                         self._data_q.put(("item", i, seeds, payload, obs.take()))
                 self._data_q.put(("done", self.sample_time, obs.take()))
             except BaseException as exc:  # noqa: BLE001 - re-raised in parent

@@ -27,13 +27,14 @@ __all__ = [
 
 
 class FeatureSource:
-    """Protocol-ish base: ``gather(rows) -> [len(rows), dim]`` float32."""
+    """Protocol-ish base: ``gather(rows) -> [len(rows), dim]`` float32;
+    ``gather(rows, out=...)`` writes the rows into ``out`` and returns it."""
 
     dim: int
     num_rows: int
     dtype = np.float32
 
-    def gather(self, rows: np.ndarray) -> np.ndarray:
+    def gather(self, rows: np.ndarray, out: np.ndarray | None = None) -> np.ndarray:
         raise NotImplementedError
 
     @property
@@ -50,8 +51,21 @@ class ArrayFeatureSource(FeatureSource):
         self.num_rows, self.dim = feats.shape
         self.dtype = feats.dtype
 
-    def gather(self, rows: np.ndarray) -> np.ndarray:
-        return self.feats[rows]
+    def gather(self, rows: np.ndarray, out: np.ndarray | None = None) -> np.ndarray:
+        """Into ``out``, one ``np.take`` writes the rows in place, with no
+        temporary array; its ``mode="clip"`` writes unbuffered, so the rows
+        are checked first."""
+        if out is None:
+            return self.feats[rows]
+        rows = np.asarray(rows)
+        if out.dtype != self.feats.dtype:  # np.take casts only where it is safe
+            out[...] = self.feats[rows]
+            return out
+        if rows.size and (rows.min() < 0 or rows.max() >= self.num_rows):
+            raise IndexError(
+                f"feature rows {rows.min()}..{rows.max()} outside 0..{self.num_rows - 1}"
+            )
+        return np.take(self.feats, rows, axis=0, out=out, mode="clip")
 
     def __repr__(self) -> str:
         return f"ArrayFeatureSource(shape={self.feats.shape})"
@@ -105,8 +119,12 @@ class StoreFeatureSource(FeatureSource):
         return cls(HybridCache(store, stack, policy=policy,
                                dynamic_frac=dynamic_frac))
 
-    def gather(self, rows: np.ndarray) -> np.ndarray:
-        return self.cache.read_rows(np.asarray(rows, dtype=np.int64))
+    def gather(self, rows: np.ndarray, out: np.ndarray | None = None) -> np.ndarray:
+        got = self.cache.read_rows(np.asarray(rows, dtype=np.int64))
+        if out is None:
+            return got
+        out[...] = got
+        return out
 
     @property
     def stats(self):

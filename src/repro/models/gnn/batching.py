@@ -5,6 +5,10 @@ vertex table and per-layer edge lists to multiples (power-of-two-ish) so jit
 recompiles only on bucket changes — this is the TPU adaptation of the
 paper's dynamic subgraph feeding (DESIGN.md §3).
 
+The batch is built in the memory it will travel in where the caller gives
+it (the forked producer's shared-memory slot, ``into``), else in fresh
+arrays; either way the feature rows are gathered straight into the table.
+
 Layer-k edge list = concat of hops 0..K-1-k (a vertex first reached at depth
 d carries its sampled one-hop edges at hop d; see core/inference/engine.py).
 """
@@ -62,72 +66,76 @@ def subgraph_to_batch(
     edge_quantum: int = 1024,
     vertex_types: np.ndarray | None = None,  # global per-vertex type table
     index: int | None = None,  # the batch's request index, for its counters
+    into=None,  # fn(layout) -> GNNBatch of arrays to build in, or None
 ) -> GNNBatch:
     """``vertex_types`` given, the batch carries its rows' types
     (``GNNBatch.vtype``).  While ``repro.obs`` records, the batch's real
     sampled edges and its padded edge slots, each summed over the layers,
-    are counted as ``batch.edges`` and ``batch.edge_slots``."""
+    are counted as ``batch.edges`` and ``batch.edge_slots``.
+
+    The build works out the batch's layout first (a :class:`GNNBatch` of
+    ``jax.ShapeDtypeStruct``: the padded sizes, from the sample's vertices
+    and edge counts), then fills arrays of that layout.  ``into`` given, it
+    is called with the layout and returns the arrays to fill, such as views
+    of the shared memory the batch will travel in; every element of them is
+    written, so they may hold an earlier batch.  Fresh arrays are filled
+    where ``into`` is None or returns None.  The feature rows are gathered
+    straight into the table, with no temporary array."""
     src = as_feature_source(feats)
     verts = sub.all_vertices()  # unique sorted gids
-    vpad = _bucket(verts.shape[0], vertex_quantum)
-    table = np.zeros((vpad, src.dim), dtype=np.float32)
-    table[: verts.shape[0]] = src.gather(verts)
-    valid = np.zeros(vpad, dtype=bool)
-    valid[: verts.shape[0]] = True
-    vtype = None
-    if vertex_types is not None:
-        vtype = np.zeros(vpad, dtype=np.int32)
-        vtype[: verts.shape[0]] = vertex_types[verts]
-
-    seed_pos = np.searchsorted(verts, sub.seeds).astype(np.int32)
-    lab = (
-        labels[sub.seeds].astype(np.int32)
-        if labels is not None
-        else np.zeros(sub.seeds.shape[0], np.int32)
-    )
-
+    n = verts.shape[0]
+    vpad = _bucket(n, vertex_quantum)
     K = num_layers
-    layer_dst, layer_src, layer_et, layer_cnt = [], [], [], []
-    for k in range(K):
-        hops = sub.hops[: K - k]
-        src = np.concatenate([h.src for h in hops]) if hops else np.zeros(0, np.int64)
-        dst = np.concatenate([h.dst for h in hops]) if hops else np.zeros(0, np.int64)
-        eid = (
-            np.concatenate([h.eid for h in hops])
-            if hops and all(h.eid is not None for h in hops)
-            else None
-        )
-        epad = _bucket(src.shape[0], edge_quantum)
-        d_pos = np.full(epad, -1, dtype=np.int32)
-        s_pos = np.full(epad, -1, dtype=np.int32)
-        et = np.zeros(epad, dtype=np.int32)
-        d_pos[: src.shape[0]] = np.searchsorted(verts, src)  # aggregation target
-        s_pos[: src.shape[0]] = np.searchsorted(verts, dst)  # message source
-        if src.shape[0]:
-            if edge_types is not None and eid is not None:
-                # direct: sampled edge ids index the global edge-type table
-                et[: src.shape[0]] = edge_types[eid]
-            elif edge_types_lookup is not None:
-                et[: src.shape[0]] = edge_types_lookup(src, dst)
-        layer_dst.append(d_pos)
-        layer_src.append(s_pos)
-        layer_et.append(et)
-        layer_cnt.append(
-            np.bincount(d_pos[d_pos >= 0], minlength=vpad)
-            .astype(np.float32)
-            .reshape(vpad, 1)
-        )
-    if obs.tracing():
-        obs.count("batch.edges", sum(int((d >= 0).sum()) for d in layer_dst), index)
-        obs.count("batch.edge_slots", sum(d.shape[0] for d in layer_dst), index)
-    return GNNBatch(
-        feats=table,
-        valid=valid,
-        seed_pos=seed_pos,
-        labels=lab,
-        layer_dst=layer_dst,
-        layer_src=layer_src,
-        layer_etype=layer_et,
-        layer_cnt=layer_cnt,
-        vtype=vtype,
+    hops_of = [sub.hops[: K - k] for k in range(K)]  # layer k's hops
+    edges = [sum(h.src.shape[0] for h in hops) for hops in hops_of]
+    epads = [_bucket(e, edge_quantum) for e in edges]
+
+    spec = jax.ShapeDtypeStruct
+    layout = GNNBatch(
+        feats=spec((vpad, src.dim), np.float32),
+        valid=spec((vpad,), bool),
+        seed_pos=spec(sub.seeds.shape, np.int32),
+        labels=spec(sub.seeds.shape, np.int32),
+        layer_dst=[spec((e,), np.int32) for e in epads],
+        layer_src=[spec((e,), np.int32) for e in epads],
+        layer_etype=[spec((e,), np.int32) for e in epads],
+        layer_cnt=[spec((vpad, 1), np.float32) for _ in epads],
+        vtype=spec((vpad,), np.int32) if vertex_types is not None else None,
     )
+    out = into(layout) if into is not None else None
+    if out is None:
+        out = jax.tree.map(lambda s: np.empty(s.shape, s.dtype), layout)
+
+    src.gather(verts, out=out.feats[:n])
+    out.feats[n:] = 0
+    out.valid[:n] = True
+    out.valid[n:] = False
+    if vertex_types is not None:
+        out.vtype[:n] = vertex_types[verts]
+        out.vtype[n:] = 0
+    out.seed_pos[:] = np.searchsorted(verts, sub.seeds)
+    out.labels[:] = labels[sub.seeds] if labels is not None else 0
+
+    for k, hops in enumerate(hops_of):
+        e = edges[k]
+        d_pos, s_pos, et = out.layer_dst[k], out.layer_src[k], out.layer_etype[k]
+        if e:
+            src_gid = np.concatenate([h.src for h in hops])
+            dst_gid = np.concatenate([h.dst for h in hops])
+            d_pos[:e] = np.searchsorted(verts, src_gid)  # aggregation target
+            s_pos[:e] = np.searchsorted(verts, dst_gid)  # message source
+            if edge_types is not None and all(h.eid is not None for h in hops):
+                # direct: sampled edge ids index the global edge-type table
+                et[:e] = edge_types[np.concatenate([h.eid for h in hops])]
+            elif edge_types_lookup is not None:
+                et[:e] = edge_types_lookup(src_gid, dst_gid)
+            else:
+                et[:e] = 0
+        d_pos[e:] = -1
+        s_pos[e:] = -1
+        et[e:] = 0
+        out.layer_cnt[k][:, 0] = np.bincount(d_pos[:e], minlength=vpad)
+    if obs.tracing():
+        obs.count("batch.edges", sum(edges), index)
+        obs.count("batch.edge_slots", sum(epads), index)
+    return out

@@ -132,6 +132,12 @@ def test_each_batch_trip_is_recorded_under_its_index(system, workers, tmp_path):
         assert set(TRIP) <= set(by[i]), (i, sorted(by[i]))
         for name in ("sample.submit", "sample.wait", "batch.build", "batch.put"):
             assert by[i][name].pid == child
+        if workers == "process":  # the wait for the slot the batch is built in
+            slot = by[i]["batch.slot"]
+            assert slot.pid == child
+            assert by[i]["sample.wait"].t1 <= slot.t0 <= slot.t1 <= by[i]["batch.build"].t0
+        else:
+            assert "batch.slot" not in by[i]
         for name in ("pipeline.recv", "pipeline.h2d"):
             assert by[i][name].pid == os.getpid()
         assert by[i]["batch.put"].t0 < by[i]["pipeline.recv"].t1

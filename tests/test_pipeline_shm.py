@@ -1,8 +1,10 @@
-"""The process-mode batch pipeline's shared-memory slots: the batch stream
-stays bit-identical to ``prefetch=0`` while the consumer holds every batch
-across several wraps of the ring, while slots regrow, through an early stop
-and a second run, through a worker killed and respawned, and where batches
-fall back to the pickled queue; ``close()`` leaves no slot behind."""
+"""The process-mode batch pipeline's shared-memory slots, which the worker
+builds each batch in: the batch stream stays bit-identical to
+``prefetch=0`` while the consumer holds every batch across several wraps of
+the ring, while slots regrow, where a smaller batch is built over a larger
+one's leftovers, with the rows' vertex types, through an early stop and a
+second run, through a worker killed and respawned, and where batches fall
+back to the pickled queue; ``close()`` leaves no slot behind."""
 import errno
 import gc
 import multiprocessing as mp
@@ -80,9 +82,26 @@ def _drive(pl, case):
     return [run]
 
 
-@pytest.mark.parametrize("case", ["wraps", "regrow", "early_stop", "respawn", "fallback"])
+def _assert_padding(batch):
+    """The padding a build writes over whatever its memory held."""
+    n = int(batch.valid.sum())
+    assert batch.valid[:n].all() and not batch.valid[n:].any()
+    assert not batch.feats[n:].any()
+    if batch.vtype is not None:
+        assert not batch.vtype[n:].any()
+    for dst, src, et in zip(batch.layer_dst, batch.layer_src, batch.layer_etype):
+        e = int((dst >= 0).sum())
+        assert (dst[e:] == -1).all() and (src[e:] == -1).all() and not et[e:].any()
+        assert (dst[:e] >= 0).all() and (src[:e] >= 0).all()
+
+
+@pytest.mark.parametrize(
+    "case", ["wraps", "regrow", "shrink", "typed", "early_stop", "respawn", "fallback"]
+)
 def test_slot_stream_is_bit_identical_to_serial(small_graph, partitioned, case, tmp_path, monkeypatch):
-    kw = dict(vertex_quantum=16, edge_quantum=16) if case == "regrow" else {}
+    kw = dict(vertex_quantum=16, edge_quantum=16) if case in ("regrow", "shrink") else {}
+    if case == "typed":
+        kw = dict(vertex_types=True)
     serial = _pipeline(small_graph, partitioned, 0, **kw)
     want = _drive(serial, case)
 
@@ -121,8 +140,12 @@ def test_slot_stream_is_bit_identical_to_serial(small_graph, partitioned, case, 
                 assert i == i0
             # the serial build of these seeds under this batch's key
             serial._req_counter = i
-            for x, y in zip(jax.tree.leaves(jax.device_get(batch)), jax.tree.leaves(serial.make_batch(seeds))):
+            host = jax.device_get(batch)
+            want_batch = serial.make_batch(seeds)
+            assert jax.tree.structure(host) == jax.tree.structure(want_batch)
+            for x, y in zip(jax.tree.leaves(host), jax.tree.leaves(want_batch)):
                 np.testing.assert_array_equal(x, y)
+            _assert_padding(host)
 
     if case == "fallback":
         assert filled == []
@@ -135,6 +158,20 @@ def test_slot_stream_is_bit_identical_to_serial(small_graph, partitioned, case, 
             grown |= slot in sizes and size > sizes[slot]
             sizes[slot] = size
         assert grown
+    if case == "shrink":  # a batch built over a larger one's leftovers
+        last = {}  # slot -> (rows, edges) of the batch it last carried
+        shrunk = False
+        for (slot, _), (_, _, batch) in zip(filled, got[0]):
+            host = jax.device_get(batch)
+            size = (int(host.valid.sum()), sum(int((d >= 0).sum()) for d in host.layer_dst))
+            shrunk |= slot in last and size[0] < last[slot][0] and size[1] < last[slot][1]
+            last[slot] = size
+        assert shrunk
+    if case == "typed":
+        for _, _, batch in got[0]:
+            host = jax.device_get(batch)
+            assert host.vtype is not None and host.vtype.any()
+            assert any(et.any() for et in host.layer_etype)
     if case == "respawn":
         assert pl.respawn_count == 1
     if traced:

@@ -3,6 +3,7 @@ legacy TwoLevelCache accounting parity, and the FeatureSource training path.
 """
 import os
 
+import jax
 import numpy as np
 import pytest
 
@@ -400,13 +401,14 @@ def test_disk_backed_features_bit_identical_batches(
     b = subgraph_to_batch(
         sub, src, small_graph.labels, 2, edge_types=small_graph.edge_types,
     )
-    np.testing.assert_array_equal(a.feats, b.feats)
-    np.testing.assert_array_equal(a.valid, b.valid)
-    np.testing.assert_array_equal(a.seed_pos, b.seed_pos)
-    np.testing.assert_array_equal(a.labels, b.labels)
-    for da, db in zip(a.layer_dst, b.layer_dst):
-        np.testing.assert_array_equal(da, db)
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_array_equal(x, y)
     assert src.stats.rows_served > 0  # the tiered path actually served
+    # gathered into a given array: the same rows as the in-memory path's
+    rows = sub.all_vertices()
+    out = np.full((rows.shape[0], src.dim), np.nan, np.float32)
+    assert src.gather(rows, out=out) is out
+    np.testing.assert_array_equal(out, as_feature_source(small_graph.vertex_feats).gather(rows))
 
 
 def test_pipeline_feature_source_end_to_end(
