@@ -17,8 +17,9 @@ power-of-two shape buckets and fed to a jit-compiled layer slice, so every
 host→device transfer and one device→host readback.  Neighbor gathers are a
 vectorized CSR-offset gather (:func:`csr_gather`) — no per-vertex Python.
 Layer fns that expose a traceable ``.jax`` slice (see
-``GNNModel.embed_layer_fn``) run under jit; plain numpy callables still work
-and get the vectorized gather without jit.
+``GNNModel.embed_layer_fn``: the slice is the training layer,
+``GNNModel.layer``, on a block) run under jit; plain numpy callables still
+work and get the vectorized gather without jit.
 
 ``mode="reference"`` preserves the pre-optimization inner loop (per-vertex
 slice-and-concatenate gathers, eager per-batch layer calls) so benchmarks

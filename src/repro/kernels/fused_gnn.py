@@ -15,8 +15,8 @@ aggregation kernels the dispatchers in ``ops.py`` reach:
   sequence in ``models/gnn/models.py``.  Uses the flash-attention online
   rescaling trick (running max / denominator in VMEM scratch, accumulator
   as the revisited output block) so segments can span edge tiles.
-* :func:`segment_max_pallas` — standalone segment-max so ``_seg_softmax``'s
-  max step can honor ``use_kernel`` too.
+* :func:`segment_max_pallas` — standalone segment-max (no model calls it:
+  with the kernel on, the softmax runs inside the kernel above).
 
 Layout, as the TPU's kernel compiler (Mosaic) needs it: per-edge vectors
 (segment ids, logits) are reshaped to ``(tiles, 1, block_edges)`` so each

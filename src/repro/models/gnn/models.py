@@ -4,7 +4,11 @@ the RelNet KGE encoder is a 2-layer HGT).
 
 All layers aggregate over padded edge lists (dst_pos, src_pos, etype) with
 -1 padding; the segment-sum hotspot goes through kernels.gnn_aggregate
-(Pallas on TPU, jnp oracle otherwise).
+(Pallas on TPU, jnp oracle otherwise).  Each kind is written once, in
+``GNNModel.layer``, which training, the layerwise engine and the server all
+run.  It follows the block convention: the destination rows are a prefix
+of the table, the layer produces its first ``n_out`` rows (all of them in
+training), and messages come from any row.
 
 HGT is the Heterogeneous Graph Transformer (Hu et al., WWW 2020,
 arXiv:2003.01332) as PyG's ``HGTConv`` has it (eq. 1-5, no relative
@@ -22,7 +26,6 @@ a weight matrix.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any
 
 import jax
@@ -33,7 +36,6 @@ from repro.kernels.ops import (
     gnn_aggregate,
     gnn_gat_aggregate,
     gnn_gather_aggregate,
-    gnn_segment_max,
     segment_spmm_ref,
 )
 
@@ -61,16 +63,14 @@ def _seg_count(seg, n, use_kernel=False):
     return _seg_sum(ones, seg, n, use_kernel)  # [n,1]
 
 
-def _seg_softmax(logits, seg, n, use_kernel=False):
-    """Softmax over edges grouped by seg (padding seg=-1 excluded)."""
-    if use_kernel:
-        mx = gnn_segment_max(logits, seg, n)
-    else:
-        neg = jnp.where(seg >= 0, logits, -jnp.inf)
-        mx = jax.ops.segment_max(neg, jnp.maximum(seg, 0), num_segments=n)
-        mx = jnp.where(jnp.isfinite(mx), mx, 0.0)
+def _seg_softmax(logits, seg, n):
+    """Softmax over edges grouped by seg (padding seg=-1 excluded), on jnp:
+    with the kernel on, ``gnn_gat_aggregate`` fuses it."""
+    neg = jnp.where(seg >= 0, logits, -jnp.inf)
+    mx = jax.ops.segment_max(neg, jnp.maximum(seg, 0), num_segments=n)
+    mx = jnp.where(jnp.isfinite(mx), mx, 0.0)
     e = jnp.where(seg >= 0, jnp.exp(logits - mx[jnp.maximum(seg, 0)]), 0.0)
-    z = _seg_sum(e[:, None], seg, n, use_kernel)[:, 0]
+    z = _seg_sum(e[:, None], seg, n, False)[:, 0]
     return e / jnp.maximum(z[jnp.maximum(seg, 0)], 1e-9)
 
 
@@ -91,13 +91,24 @@ def _per_relation(x, w, src, rel):
 
 
 def _seg_softmax_aggregate(logits, msg, seg, n, use_kernel):
-    """out[s] = sum_e softmax_{seg==s}(logits)[e] * msg[e] — the GAT/HGT
-    per-head inner loop.  One Pallas kernel when enabled, the original
+    """out[s] = sum_e softmax_{seg==s}(logits)[e] * msg[e] — one head of
+    the GAT/HGT inner loop.  One Pallas kernel when enabled, the original
     3-pass ``_seg_softmax`` + ``_seg_sum`` otherwise."""
     if use_kernel:
         return gnn_gat_aggregate(logits, msg, seg, n)
-    alpha = _seg_softmax(logits, seg, n, use_kernel)
-    return _seg_sum(msg * alpha[:, None], seg, n, use_kernel)
+    alpha = _seg_softmax(logits, seg, n)
+    return _seg_sum(msg * alpha[:, None], seg, n, False)
+
+
+def _heads_softmax_aggregate(logits, msg, seg, n, use_kernel):
+    """:func:`_seg_softmax_aggregate` for each head i of ``logits`` [E, H]
+    and ``msg`` [E, H, d], the heads concatenated: one head at a time keeps
+    the segment ops 2-D."""
+    return jnp.concatenate(
+        [_seg_softmax_aggregate(logits[:, i], msg[:, i], seg, n, use_kernel)
+         for i in range(logits.shape[1])],
+        axis=1,
+    )
 
 
 class GNNModel:
@@ -187,10 +198,11 @@ class GNNModel:
             p["w_in"], p["b_in"] = lin(ks[6], in_dim), jnp.zeros((T, D))
         return p
 
-    def _hgt_layer(self, p: Params, h, dst, src, etype, vt, use_kernel: bool):
+    def _hgt_layer(self, p: Params, h, dst, src, etype, vt, *, n_out, use_kernel: bool):
         """One HGT layer (module docstring) over rows ``h`` of vertex types
-        ``vt`` and the padded edge list; ``vt`` may be None only where there
-        is one vertex type."""
+        ``vt`` and the padded edge list, producing rows ``[0, n_out)``
+        (:meth:`layer`); ``vt`` may be None only where there is one vertex
+        type."""
         n, heads = h.shape[0], self.num_heads
         if vt is None:
             if self.num_vtypes > 1:
@@ -201,11 +213,16 @@ class GNNModel:
         if "w_in" in p:  # layer 0: the input adapter
             with jax.named_scope("hgt.typed_linear"):
                 h = jnp.tanh(_typed_linear(h, p["w_in"], p["b_in"], vt))
+        m, h_out, vt_out = n, h, vt
+        if n_out is not None and n_out != n:
+            m, h_out, vt_out = n_out, h[:n_out], vt[:n_out]
         d = h.shape[1] // heads
         with jax.named_scope("hgt.typed_linear"):
             q, k, v = (
-                _typed_linear(h, p[w], p[b], vt).reshape(n, heads, d)
-                for w, b in (("wq", "bq"), ("wk", "bk"), ("wv", "bv"))
+                _typed_linear(x, p[w], p[b], t).reshape(-1, heads, d)
+                for x, t, w, b in (
+                    (h_out, vt_out, "wq", "bq"), (h, vt, "wk", "bk"), (h, vt, "wv", "bv")
+                )
             )
         with jax.named_scope("hgt.relation"):
             rel = jnp.maximum(etype, 0)
@@ -214,56 +231,67 @@ class GNNModel:
             m_e = jnp.where((src >= 0)[:, None, None], _per_relation(v, p["w_msg"], s, rel), 0.0)
         with jax.named_scope("hgt.attention"):
             att = (q[jnp.maximum(dst, 0)] * k_e).sum(-1) * p["mu"][rel] / d**0.5  # [E, H]
-            agg = jnp.concatenate(
-                [_seg_softmax_aggregate(att[:, i], m_e[:, i], dst, n, use_kernel) for i in range(heads)],
-                axis=1,
-            )
+            agg = _heads_softmax_aggregate(att, m_e, dst, m, use_kernel)
         with jax.named_scope("hgt.output"):
-            o = _typed_linear(jax.nn.gelu(agg, approximate=False), p["wa"], p["ba"], vt)
-            beta = jax.nn.sigmoid(p["skip"][vt])[:, None]
-            return beta * o + (1.0 - beta) * h
+            o = _typed_linear(jax.nn.gelu(agg, approximate=False), p["wa"], p["ba"], vt_out)
+            beta = jax.nn.sigmoid(p["skip"][vt_out])[:, None]
+            return beta * o + (1.0 - beta) * h_out
 
     # -- single layer ---------------------------------------------------------
     def layer(
-        self, p: Params, k: int, h: jax.Array, dst, src, etype, cnt=None, vtype=None
+        self, p: Params, k: int, h: jax.Array, dst, src, etype, cnt=None, vtype=None,
+        *, n_out: int | None = None, use_kernel: bool | None = None,
     ) -> jax.Array:
-        """``cnt`` is the optional precomputed in-degree column ([n, 1],
-        valid-edge counts per destination) — static per batch, so callers
-        with a ``GNNBatch.layer_cnt`` pass it instead of recomputing the
+        """Layer ``k`` over the table ``h``: messages come from ``h[src]``
+        over the whole table, and the layer produces rows ``[0, n_out)``
+        (all rows when None), into which ``dst`` points.  ``cnt`` is the
+        optional precomputed in-degree column ([n_out, 1], valid-edge counts
+        per destination) — static per batch, so callers with a
+        ``GNNBatch.layer_cnt`` pass it instead of recomputing the
         segment-count here on every layer call.  ``vtype`` is each row's
-        vertex type, read by hgt."""
-        n = h.shape[0]
+        vertex type, read by hgt; ``use_kernel`` defaults to the model's."""
+        use_kernel = self.use_kernel if use_kernel is None else use_kernel
         ok = src >= 0
+        if self.kind == "hgt":
+            return self._hgt_layer(
+                p, h, dst, src, etype, vtype, n_out=n_out, use_kernel=use_kernel
+            )
+        n, h_out = h.shape[0], h
+        if n_out is not None and n_out != n:
+            n, h_out = n_out, h[:n_out]
         if self.kind in ("gcn", "sage"):
             # fused path gathers h[src] inside the kernel's edge tiles
-            agg = _gather_seg_sum(h, src, dst, n, self.use_kernel)
+            agg = _gather_seg_sum(h, src, dst, n, use_kernel)
             if cnt is None:
-                cnt = _seg_count(dst, n, self.use_kernel)
+                cnt = _seg_count(dst, n, use_kernel)
             if self.kind == "gcn":
-                return jax.nn.relu(((agg + h) / (cnt + 1.0)) @ p["w"] + p["b"])
+                return jax.nn.relu(((agg + h_out) / (cnt + 1.0)) @ p["w"] + p["b"])
             return jax.nn.relu(
-                jnp.concatenate([h, agg / jnp.maximum(cnt, 1.0)], axis=1) @ p["w"]
+                jnp.concatenate([h_out, agg / jnp.maximum(cnt, 1.0)], axis=1) @ p["w"]
                 + p["b"]
             )
         if self.kind == "gat":
             heads, dh = p["a_dst"].shape
-            z = (h @ p["w"]).reshape(n, heads, dh)
+            z = (h @ p["w"]).reshape(-1, heads, dh)
             zsrc = jnp.where(ok[:, None, None], z[jnp.maximum(src, 0)], 0.0)
             zdst = jnp.where((dst >= 0)[:, None, None], z[jnp.maximum(dst, 0)], 0.0)
             e = jax.nn.leaky_relu(
                 (zdst * p["a_dst"]).sum(-1) + (zsrc * p["a_src"]).sum(-1), 0.2
             )  # [E, H]
-            out = []
-            for hd in range(heads):  # few heads; keeps segment ops 2-D
-                out.append(
-                    _seg_softmax_aggregate(
-                        e[:, hd], zsrc[:, hd], dst, n, self.use_kernel
-                    )
-                )
-            return jax.nn.elu(jnp.concatenate(out, axis=1))
-        if self.kind == "hgt":
-            return self._hgt_layer(p, h, dst, src, etype, vtype, self.use_kernel)
+            return jax.nn.elu(_heads_softmax_aggregate(e, zsrc, dst, n, use_kernel))
         raise ValueError(self.kind)
+
+    def kernel_shapes(self, num_edges: int, num_vertices: int, in_dim: int) -> list:
+        """(op, (edges, segments, dim)) tuples a layer with the kernel on
+        dispatches over ``num_edges`` edges into ``num_vertices`` rows of
+        width ``in_dim`` — the engine hands them to the autotuner before a
+        bucket's first jit trace so tuned blocks bake into the compile."""
+        if self.kind in ("gcn", "sage"):
+            return [
+                ("segment_spmm_ragged", (num_edges, num_vertices, in_dim)),
+                ("segment_spmm_ragged", (num_edges, num_vertices, 1)),
+            ]
+        return [("gat_softmax_aggregate", (num_edges, num_vertices, self.hidden // self.num_heads))]
 
     # -- full apply --------------------------------------------------------------
     def apply(self, params: Params, batch) -> jax.Array:
@@ -294,67 +322,40 @@ class GNNModel:
         return (logz - tgt).mean()
 
     def embed_layer_fn(self, params: Params, k: int, *, use_kernel: bool | None = None):
-        """Adapter for the layerwise inference engine: one slice of the model
-        as (k, h_self, h_nbr, seg[, etype[, vt_self, vt_nbr]]) -> h_new
-        (numpy in/out).
+        """Adapter for the layerwise inference engine and the server: layer
+        ``k`` of the model as (k, h_self, h_nbr, seg[, etype[, vt_self,
+        vt_nbr]]) -> h_new (numpy in/out), with one row of ``h_nbr`` per
+        sampled edge and ``seg`` (-1 padding) its self row.
 
-        The returned callable carries two engine-facing attributes:
+        The slice is :meth:`layer` itself on a block: the table is the self
+        rows followed by the neighbour rows, so the destination rows are its
+        prefix, ``n_out`` is the number of self rows, and edge e's source is
+        row ``len(h_self) + e``.
+
+        The returned callable carries three engine-facing attributes:
 
         * ``fn.jax(h_self, h_nbr, seg, etype[, vt_self, vt_nbr], *,
-          use_kernel=...)`` — the pure traceable slice on jnp arrays with
-          ``seg == -1`` padding, which the bucketed engine wraps in
-          ``jax.jit`` so each (layer, shape-bucket) pair compiles once and
-          stays device-resident.
+          use_kernel=...)`` — the pure traceable slice on jnp arrays, which
+          the bucketed engine wraps in ``jax.jit`` so each (layer,
+          shape-bucket) pair compiles once and stays device-resident;
+          ``use_kernel`` defaults to this call's, else the model's flag.
         * ``fn.typed`` — True for hgt, whose per-relation and per-type
           projections need the sampled edges' type ids and the vertex types
           of the self rows and of the neighbour rows.
-
-        Covers all four evaluated kinds (gcn/sage/gat/hgt); aggregation goes
-        through :func:`repro.kernels.ops.gnn_aggregate` when ``use_kernel``
-        (defaulting to the model's flag) is set."""
+        * ``fn.kernel_shapes`` — :meth:`kernel_shapes`."""
         p = params["layers"][k]
-        kind = self.kind
-        heads = self.num_heads
         default_kernel = self.use_kernel if use_kernel is None else use_kernel
 
         def jax_fn(h_self, h_nbr, seg, etype, vt_self=None, vt_nbr=None, *, use_kernel=default_kernel):
-            n = h_self.shape[0]
+            m, e = h_self.shape[0], h_nbr.shape[0]
             seg = seg.astype(jnp.int32)
-            ok = seg >= 0
-            if kind == "gcn":
-                agg = _seg_sum(h_nbr, seg, n, use_kernel)
-                cnt = _seg_count(seg, n, use_kernel) + 1.0
-                return jax.nn.relu(((agg + h_self) / cnt) @ p["w"] + p["b"])
-            if kind == "sage":
-                agg = _seg_sum(h_nbr, seg, n, use_kernel)
-                cnt = jnp.maximum(_seg_count(seg, n, use_kernel), 1.0)
-                return jax.nn.relu(
-                    jnp.concatenate([h_self, agg / cnt], axis=1) @ p["w"] + p["b"]
-                )
-            if kind == "gat":
-                hh, dh = p["a_dst"].shape
-                z = (h_self @ p["w"]).reshape(n, hh, dh)
-                zsrc = (h_nbr @ p["w"]).reshape(-1, hh, dh)
-                zsrc = jnp.where(ok[:, None, None], zsrc, 0.0)
-                zdst = z[jnp.maximum(seg, 0)]
-                e = jax.nn.leaky_relu(
-                    (zdst * p["a_dst"]).sum(-1) + (zsrc * p["a_src"]).sum(-1), 0.2
-                )  # [E, H]
-                out = []
-                for hd in range(hh):
-                    out.append(
-                        _seg_softmax_aggregate(e[:, hd], zsrc[:, hd], seg, n, use_kernel)
-                    )
-                return jax.nn.elu(jnp.concatenate(out, axis=1))
-            if kind == "hgt":
-                # the layer itself over the self rows followed by one row
-                # per neighbour edge; only the self rows' outputs are kept
-                m, e = h_self.shape[0], h_nbr.shape[0]
-                src = jnp.where(ok, m + jnp.arange(e, dtype=jnp.int32), -1)
-                vt = None if vt_self is None else jnp.concatenate([vt_self, vt_nbr]).astype(jnp.int32)
-                h = jnp.concatenate([h_self, h_nbr])
-                return self._hgt_layer(p, h, seg, src, etype.astype(jnp.int32), vt, use_kernel)[:m]
-            raise ValueError(kind)
+            src = jnp.where(seg >= 0, m + jnp.arange(e, dtype=jnp.int32), -1)
+            vt = None if vt_self is None else jnp.concatenate([vt_self, vt_nbr]).astype(jnp.int32)
+            h = jnp.concatenate([h_self, h_nbr])
+            return self.layer(
+                p, k, h, seg, src, etype.astype(jnp.int32),
+                vtype=vt, n_out=m, use_kernel=use_kernel,
+            )
 
         def fn(_k, h_self, h_nbr, seg, etype=None, vt_self=None, vt_nbr=None):
             m = h_nbr.shape[0]
@@ -369,19 +370,7 @@ class GNNModel:
                 jax_fn(jnp.asarray(h_self), jnp.asarray(h_nbr), sg, et, *types)
             )
 
-        def kernel_shapes(num_edges, num_vertices, in_dim):
-            """(op, (edges, segments, dim)) tuples this slice dispatches at
-            the given bucket — the engine hands them to the autotuner before
-            a bucket's first jit trace so tuned blocks bake into the compile."""
-            if kind in ("gcn", "sage"):
-                return [
-                    ("segment_spmm_ragged", (num_edges, num_vertices, in_dim)),
-                    ("segment_spmm_ragged", (num_edges, num_vertices, 1)),
-                ]
-            dh = p["a_dst"].shape[1] if kind == "gat" else self.hidden // heads
-            return [("gat_softmax_aggregate", (num_edges, num_vertices, dh))]
-
         fn.jax = jax_fn
         fn.typed = self.reads_vertex_types
-        fn.kernel_shapes = kernel_shapes
+        fn.kernel_shapes = self.kernel_shapes
         return fn

@@ -182,3 +182,32 @@ def test_gnn_seg_ops_honor_use_kernel():
         ref = model.embed_layer_fn(params, 0, use_kernel=False)(0, hs, hn, seg, et)
         ker = model.embed_layer_fn(params, 0, use_kernel=True)(0, hs, hn, seg, et)
         np.testing.assert_allclose(ref, ker, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("kind", ["gcn", "sage", "gat", "hgt"])
+def test_inference_slice_is_the_training_layer(kind):
+    """The layerwise slice, handed the self rows and one row per edge (with
+    the rows' vertex types), is the training layer over the whole table:
+    every row agrees, padding edges (-1) included."""
+    from repro.models.gnn import GNNModel
+
+    model = GNNModel(kind, 24, hidden=32, num_layers=2, num_classes=7, num_heads=4,
+                     num_etypes=5, num_vtypes=3 if kind == "hgt" else 1)
+    params = model.init(jax.random.PRNGKey(2))
+    rng = np.random.default_rng(4)
+    n, e = 40, 160
+    h = rng.standard_normal((n, 24)).astype(np.float32)
+    vt = rng.integers(0, 3, n).astype(np.int32)
+    dst = np.sort(rng.integers(0, n, e)).astype(np.int32)
+    src = rng.integers(0, n, e).astype(np.int32)
+    pad = rng.random(e) < 0.2
+    dst[pad], src[pad] = -1, -1
+    et = rng.integers(0, 5, e).astype(np.int32)
+    want = model.layer(params["layers"][0], 0, h, dst, src, et, vtype=vt)
+    fn = model.embed_layer_fn(params, 0)
+    assert fn.typed == (kind == "hgt")
+    got = fn(0, h, h[src], dst, et, vt, vt[src])
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    if kind == "hgt":
+        with pytest.raises(ValueError, match="vertex types"):  # no row types, no guess
+            fn(0, h, h[src], dst, et)
